@@ -17,7 +17,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -27,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.timing import provenance
 from repro.obs.timing import timeit as _timeit
 
@@ -133,23 +133,24 @@ def bench_table1_table2():
 def bench_kernels():
     from repro.kernels.topk_scoring.ops import topk_scores
     from repro.kernels.topk_scoring.ref import topk_scores_ref
-    from repro.kernels.label_prop.ops import label_prop_round
+    from repro.kernels.label_prop.ops import label_prop_round_t
     from repro.core.graph_builder import EdgeList, symmetrize
 
     key = jax.random.PRNGKey(0)
     q = jax.random.normal(key, (64, 64))
     c = jax.random.normal(jax.random.PRNGKey(1), (8192, 64))
-    row("kernel_topk_scoring(pallas-interpret)",
+    row("kernel_topk_scoring(pallas)",
         _timeit(lambda: topk_scores(q, c, k=8)), "k=8 n=8192")
     row("kernel_topk_scoring(jnp-ref)",
         _timeit(lambda: topk_scores_ref(q, c, k=8)), "k=8 n=8192")
 
     n, kdeg = 4096, 16
-    nbr = jax.random.randint(key, (n, kdeg), -1, n)
-    wgt = jnp.abs(jax.random.normal(key, (n, kdeg)))
+    nbr = jax.random.randint(key, (kdeg, n), -1, n)     # slot-major ELL
+    wgt = jnp.abs(jax.random.normal(key, (kdeg, n)))
     labels = jnp.arange(n, dtype=jnp.int32)
-    row("kernel_label_prop(pallas-interpret)",
-        _timeit(lambda: label_prop_round(labels, nbr, wgt)), f"n={n} K={kdeg}")
+    row("kernel_label_prop(pallas)",
+        _timeit(lambda: label_prop_round_t(labels, nbr, wgt)),
+        f"n={n} K={kdeg}")
 
     # every registered LP engine, side-by-side on the same graph (the §Perf
     # trade for Alg. 2: sort's O(E log E) shuffle vs ELL's dense O(N K^2))
@@ -245,7 +246,7 @@ def bench_retrieval():
     # tuned-vs-default speedup column per kernel primitive x size: explicit
     # default blocks vs the autotuner table's resolution (explicit kwargs on
     # both sides, so stale jit caches can't blur the comparison)
-    from repro.kernels.lsh_hamming.ops import hamming_topk
+    from repro.kernels.lsh_hamming.ops import hamming_topk_t
     from repro.kernels.topk_scoring.ops import topk_scores, topk_scores_int8
     from repro.retrieval.lsh import build_lsh, encode
     for n in sizes:
@@ -260,7 +261,7 @@ def bench_retrieval():
             ("topk", "int8"):
                 lambda blk: topk_scores_int8(q8, c8, k=k, **blk),
             ("hamming_topk", "int32"):
-                lambda blk: hamming_topk(qcodes, lsh.codes, k=k, **blk),
+                lambda blk: hamming_topk_t(qcodes, lsh.codes, k=k, **blk),
         }
         for (kernel, dt), fn in cases.items():
             default = dict(tuning.DEFAULTS[kernel])
@@ -288,25 +289,23 @@ def bench_retrieval():
 # ---------------------------------------------------------------------------
 # Streamed shard-local build (DESIGN.md §13): weak-scaling rows — the
 # per-shard size is held constant while the shard count (and hence total
-# corpus) grows, so the per-device peak should stay flat.  Each point runs
-# in a subprocess because the host device count is fixed at backend
-# startup (XLA_FLAGS=--xla_force_host_platform_device_count=<shards>).
+# corpus) grows, so the per-device peak should stay flat.  Every point runs
+# in this process on a mesh over the first ``shards`` devices present (a
+# CPU run gets several with XLA_FLAGS=--xla_force_host_platform_device_
+# count=<n>).  A high-water mark never resets within a process, so each
+# point reports what its build left resident: the bytes held per device
+# after the build less those held before it.
 # ---------------------------------------------------------------------------
 
-def _streamed_child(spec: str) -> None:
-    """Hidden subprocess entry: build one streamed session and print a
-    machine-readable result line (``STREAMED_CHILD {json}``)."""
-    kind, per_shard, shards, chunk = spec.split(":")
-    per_shard, shards, chunk = int(per_shard), int(shards), int(chunk)
-    from jax.sharding import Mesh
-    from repro.obs.memory import PEAK_GAUGE
-    from repro.obs.metrics import REGISTRY
-    devs = jax.devices()
-    if len(devs) < shards:
-        raise SystemExit(f"need {shards} devices, have {len(devs)} "
-                         f"(set XLA_FLAGS=--xla_force_host_platform_"
-                         f"device_count={shards})")
-    mesh = Mesh(np.array(devs[:shards]), ("data",))
+def _streamed_point(kind: str, per_shard: int, shards: int,
+                    chunk: int = 65536) -> dict:
+    """Build one streamed session over ``shards`` devices and time it."""
+    import gc
+
+    from repro.launch.mesh import make_mesh
+    from repro.obs.memory import resident_bytes_per_device
+    mesh = make_mesh((shards,), ("data",), devices=jax.devices()[:shards])
+    before = resident_bytes_per_device()
     out = {"kind": kind, "per_shard": per_shard, "shards": shards}
     if kind == "retrieval":
         from repro.retrieval.search_core import SearchConfig, SearchSession
@@ -325,7 +324,7 @@ def _streamed_child(spec: str) -> None:
         out["build_us"] = (time.time() - t0) * 1e6
         out["search_us"] = _timeit(lambda: session.search(queries, k=k))
         out["n"] = n
-    elif kind == "sampling":
+    else:
         from repro.core import QRelTable
         from repro.core import sampling_core as sc
         from repro.data.synthetic import generate_corpus
@@ -348,37 +347,19 @@ def _streamed_child(spec: str) -> None:
                                  n=1)
         out["n"] = corpus.num_entities
         out["nq"] = nq
-    else:
-        raise SystemExit(f"unknown streamed-child kind {kind!r}")
-    out["peak_bytes_per_device"] = int(REGISTRY.gauge(PEAK_GAUGE).value)
-    print("STREAMED_CHILD " + json.dumps(out), flush=True)
+    after = resident_bytes_per_device()
+    out["peak_bytes_per_device"] = max(after[d] - before[d] for d in after)
+    del session
+    gc.collect()
+    return out
 
 
-def _run_streamed_point(kind: str, per_shard: int, shards: int,
-                        chunk: int = 65536) -> dict:
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count="
-                        f"{shards}").strip()
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.run", "--streamed-child",
-         f"{kind}:{per_shard}:{shards}:{chunk}"],
-        capture_output=True, text=True, env=env,
-        cwd=os.path.join(os.path.dirname(__file__), ".."))
-    for line in proc.stdout.splitlines():
-        if line.startswith("STREAMED_CHILD "):
-            return json.loads(line[len("STREAMED_CHILD "):])
-    raise RuntimeError(
-        f"streamed child {kind}:{per_shard}:{shards} failed "
-        f"(rc={proc.returncode}):\n{proc.stderr[-2000:]}")
-
-
-def _streamed_rows(kind: str, per_shard: int,
-                   shard_counts=(1, 2)) -> None:
+def _streamed_rows(kind: str, per_shard: int) -> None:
+    n_dev = len(jax.devices())
+    shard_counts = [s for s in (1, 2, 4, 8) if s <= n_dev]
     peaks = {}
     for shards in shard_counts:
-        r = _run_streamed_point(kind, per_shard, shards)
+        r = _streamed_point(kind, per_shard, shards)
         peaks[shards] = r["peak_bytes_per_device"]
         work_us = r.get("search_us", r.get("draw_us", 0.0))
         tag = (f"{kind}_streamed[exact|jnp|N={r['n']}|shards={shards}]"
@@ -631,12 +612,9 @@ def main() -> None:
     p.add_argument("--json", default=None, metavar="PATH",
                    help="directory to persist each section's rows as "
                         "BENCH_<name>.json (the perf trajectory record)")
-    p.add_argument("--streamed-child", default=None, help=argparse.SUPPRESS)
     args = p.parse_args()
-    if args.streamed_child:
-        _streamed_child(args.streamed_child)
-        return
     SMOKE = args.smoke
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(BENCHES)
     print("name,us_per_call,derived")
     if args.autotune:

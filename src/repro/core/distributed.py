@@ -1,6 +1,7 @@
 """Multi-device WindTunnel core: shard_map label propagation.
 
-Node-sharded ELL layout: each device owns N/d rows of the (N, K) adjacency;
+Node-sharded ELL layout: each device owns N/d nodes of the adjacency,
+held slot-major (K, N/d) as the single-device engines hold it;
 labels are the replicated carry. One round = local dense LP round (the
 Pallas kernel's computation) + all_gather of the new local labels — one
 collective per round, which is the distributed-LP communication lower bound
@@ -9,59 +10,45 @@ the DESIGN.md §2 port at the multi-pod level.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
 
-from repro.core.label_prop import ell_round
-from repro.distributed.collectives import pvary_compat, unvary_compat
+from repro.kernels.label_prop.ref import round_slot_major
 
 
 def distributed_propagate_ell(mesh: Mesh, nbr: jnp.ndarray, wgt: jnp.ndarray,
                               *, rounds: int, axis: str = "data"):
-    """nbr (N, K) i32 / wgt (N, K) f32, N divisible by mesh axis size.
-    Returns final labels (N,) i32 (replicated)."""
+    """nbr (N, K) i32 / wgt (N, K) f32, N divisible by mesh axis size,
+    transposed once to the slot-major (K, N) layout.  Returns final labels
+    (N,) i32 (replicated)."""
     n = nbr.shape[0]
 
     def local_rounds(nbr_l, wgt_l):
-        # nbr_l/wgt_l: (N/d, K) local rows; labels: (N,) replicated carry
+        # nbr_l/wgt_l: (K, N/d) local nodes; labels: (N,) replicated carry
         idx = lax.axis_index(axis)
-        rows = nbr_l.shape[0]
+        rows = nbr_l.shape[1]
         row0 = idx * rows
 
         def one(labels, _):
             local_own = lax.dynamic_slice(labels, (row0,), (rows,))
             lab = jnp.where(nbr_l >= 0, labels[jnp.maximum(nbr_l, 0)], -1)
-            # same semantics as core.label_prop.ell_round on the local rows
-            mask = nbr_l >= 0
-            w = jnp.where(mask, wgt_l, 0.0)
-            same = (lab[:, :, None] == lab[:, None, :]).astype(jnp.float32)
-            scores = jnp.einsum("nkj,nk->nj", same, w)
-            scores = jnp.where(mask, scores, -jnp.inf)
-            smax = jnp.max(scores, axis=1, keepdims=True)
-            cand = jnp.where((scores == smax) & mask, lab,
-                             jnp.iinfo(jnp.int32).max)
-            best = jnp.min(cand, axis=1)
-            has = jnp.any(mask, axis=1)
-            new_local = jnp.where(has, best, local_own).astype(jnp.int32)
+            new_local = round_slot_major(lab, wgt_l, local_own)
             new_labels = lax.all_gather(new_local, axis, tiled=True)
             return new_labels, None
 
         labels0 = jnp.arange(n, dtype=jnp.int32)
-        # mark the replicated carry as device-varying (shard_map scan rule;
-        # no-op on JAX versions without varying-manual-axes tracking)
-        labels0 = pvary_compat(labels0, (axis,))
+        # the all-gathered carry is device-varying to shard_map's type
+        # rule; the equal per-shard results collapse back with a pmax
+        labels0 = lax.pcast(labels0, (axis,), to="varying")
         labels, _ = lax.scan(one, labels0, None, length=rounds)
-        return unvary_compat(labels, (axis,))  # collapse the annotation
+        return lax.pmax(labels, (axis,))
 
     fn = shard_map(local_rounds, mesh=mesh,
-                   in_specs=(P(axis, None), P(axis, None)),
+                   in_specs=(P(None, axis), P(None, axis)),
                    out_specs=P())
-    return fn(nbr, wgt)
+    return fn(nbr.T, wgt.T)
 
 
 def verify_against_single_device(mesh, nbr, wgt, rounds=3):

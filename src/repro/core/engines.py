@@ -107,8 +107,8 @@ class _EdgeListState(NamedTuple):
 
 
 class _EllState(NamedTuple):
-    nbr: jnp.ndarray   # i32[N, K] neighbour ids, -1 padding
-    wgt: jnp.ndarray   # f32[N, K]
+    nbr_t: jnp.ndarray   # i32[K, N] neighbour ids, -1 padding (slot-major)
+    wgt_t: jnp.ndarray   # f32[K, N]
 
 
 @register
@@ -133,19 +133,20 @@ class SortEngine:
 
 @register
 class EllEngine:
-    """Dense degree-capped engine: the (N, K) ELL layout the Pallas kernel
-    consumes, executed as plain XLA einsum/argmax."""
+    """Dense degree-capped engine: the slot-major (K, N) ELL layout the
+    Pallas kernel consumes, scored in plain XLA with the kernel's own
+    fixed-order compare-and-add, so the two engines agree bit for bit."""
 
     name = "ell"
 
     def prepare(self, src, dst, w, valid, *, num_nodes: int,
                 max_degree: int) -> _EllState:
-        return _EllState(*lp.edges_to_ell(src, dst, w, valid,
-                                          num_nodes=num_nodes,
-                                          max_degree=max_degree))
+        return _EllState(*lp.edges_to_ell_t(src, dst, w, valid,
+                                            num_nodes=num_nodes,
+                                            max_degree=max_degree))
 
     def round(self, labels, state: _EllState):
-        return lp.ell_round(labels, state.nbr, state.wgt)
+        return lp.ell_round_t(labels, state.nbr_t, state.wgt_t)
 
     def finalize(self, labels, changes):
         return lp.LabelPropResult(labels, changes)
@@ -170,14 +171,14 @@ class PallasEngine:
 
     def prepare(self, src, dst, w, valid, *, num_nodes: int,
                 max_degree: int) -> _EllState:
-        return _EllState(*lp.edges_to_ell(src, dst, w, valid,
-                                          num_nodes=num_nodes,
-                                          max_degree=max_degree))
+        return _EllState(*lp.edges_to_ell_t(src, dst, w, valid,
+                                            num_nodes=num_nodes,
+                                            max_degree=max_degree))
 
     def round(self, labels, state: _EllState):
-        from repro.kernels.label_prop.ops import label_prop_round
-        return label_prop_round(labels, state.nbr, state.wgt,
-                                block_n=self.block_n)
+        from repro.kernels.label_prop.ops import label_prop_round_t
+        return label_prop_round_t(labels, state.nbr_t, state.wgt_t,
+                                  block_n=self.block_n)
 
     def finalize(self, labels, changes):
         return lp.LabelPropResult(labels, changes)

@@ -19,7 +19,7 @@ unspecified; a deterministic rule makes the pipeline reproducible.
 Pallas label_prop kernel (kernels/label_prop) — same semantics, different
 data layout (see ref.py there for the oracle correspondence).
 
-The per-round functions here (``sort_round``, ``ell_round``) are the
+The per-round functions here (``sort_round``, ``ell_round_t``) are the
 building blocks the engine registry (engines.py, DESIGN.md §4) wraps into
 uniformly selectable execution strategies.
 """
@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import segment_utils as su
+from repro.kernels.label_prop.ref import round_slot_major
 
 
 class LabelPropResult(NamedTuple):
@@ -90,14 +91,16 @@ def propagate(src, dst, w, valid, *, num_nodes: int, rounds: int) -> LabelPropRe
 # Dense ELL formulation (feeds the Pallas kernel; also the vmap-able oracle)
 # ---------------------------------------------------------------------------
 
-def edges_to_ell(src, dst, w, valid, *, num_nodes: int, max_degree: int):
-    """Pack a directed edge list into ELL adjacency:
-    nbr i32[num_nodes, max_degree] (pad -1), wgt f32[num_nodes, max_degree].
+def edges_to_ell_t(src, dst, w, valid, *, num_nodes: int, max_degree: int):
+    """Pack a directed edge list into slot-major ELL adjacency:
+    nbr_t i32[max_degree, num_nodes] (pad -1), wgt_t f32[max_degree,
+    num_nodes] — nodes along the last (lane) dim, the layout the LP
+    engines keep (a node-major (N, K) array with small K wastes most of
+    its TPU tiles).
 
     Edges beyond ``max_degree`` per dst are dropped deterministically
     (highest-weight edges kept), mirroring the fanout cap of Alg. 1.
     """
-    e = src.shape[0]
     dst_k = jnp.where(valid, dst, num_nodes)
     negw = jnp.where(valid, -w, jnp.inf)
     (dsts, _), (srcs, ws) = su.sort_by((dst_k, negw), (src, w))
@@ -106,41 +109,51 @@ def edges_to_ell(src, dst, w, valid, *, num_nodes: int, max_degree: int):
     ok = (dsts < num_nodes) & (rank < max_degree)
     row = jnp.where(ok, dsts, num_nodes)
     col = jnp.where(ok, rank, 0)
-    nbr = jnp.full((num_nodes, max_degree), -1, jnp.int32)
-    nbr = nbr.at[row, col].set(srcs.astype(jnp.int32), mode="drop")
-    wgt = jnp.zeros((num_nodes, max_degree), jnp.float32)
-    wgt = wgt.at[row, col].set(ws, mode="drop")
+    nbr = jnp.full((max_degree, num_nodes), -1, jnp.int32)
+    nbr = nbr.at[col, row].set(srcs.astype(jnp.int32), mode="drop")
+    wgt = jnp.zeros((max_degree, num_nodes), jnp.float32)
+    wgt = wgt.at[col, row].set(ws, mode="drop")
     return nbr, wgt
 
 
+def edges_to_ell(src, dst, w, valid, *, num_nodes: int, max_degree: int):
+    """Node-major ELL adjacency: nbr i32[num_nodes, max_degree] (pad -1),
+    wgt f32[num_nodes, max_degree] — :func:`edges_to_ell_t` transposed,
+    for callers that hold (N, K) arrays."""
+    nbr_t, wgt_t = edges_to_ell_t(src, dst, w, valid, num_nodes=num_nodes,
+                                  max_degree=max_degree)
+    return nbr_t.T, wgt_t.T
+
+
+def ell_round_t(labels, nbr_t, wgt_t):
+    """One LP round over slot-major ELL adjacency (K, N) — the ``ell``
+    engine's round, and the computation the Pallas kernel implements."""
+    lab = jnp.where(nbr_t >= 0, labels[jnp.maximum(nbr_t, 0)], -1)
+    return round_slot_major(lab, wgt_t, labels).astype(labels.dtype)
+
+
 def ell_round(labels, nbr, wgt):
-    """One LP round over ELL adjacency. O(N * K^2) but fully dense —
-    this is the computation the Pallas kernel implements on TPU.
+    """One LP round over node-major ELL adjacency (N, K): transposes it to
+    :func:`ell_round_t` on every call.  O(N * K^2) but fully dense.
 
     For node n with neighbour labels l_k and weights w_k:
       S(l_j) = sum_k w_k [l_k == l_j];  L* = argmax_j (S, -l_j).
-    Nodes with no neighbours keep their label.
+    Nodes with no neighbours keep their label.  Scores accumulate in the
+    kernel's fixed slot order (kernels/label_prop ``same_label_scores``),
+    so this engine and the ``pallas`` engine agree bit for bit.
     """
-    mask = nbr >= 0                                        # (N, K)
-    lab = jnp.where(mask, labels[jnp.maximum(nbr, 0)], -1)  # (N, K)
-    w = jnp.where(mask, wgt, 0.0)
-    same = lab[:, :, None] == lab[:, None, :]               # (N, K, K)
-    scores = jnp.einsum("nkj,nk->nj", same.astype(w.dtype), w)
-    scores = jnp.where(mask, scores, -jnp.inf)
-    # argmax with tie -> smaller label: exact two-pass (max score, min label)
-    smax = jnp.max(scores, axis=1, keepdims=True)
-    cand = jnp.where((scores == smax) & mask, lab, su.I32_MAX)
-    new = jnp.min(cand, axis=1)
-    has_nbr = jnp.any(mask, axis=1)
-    return jnp.where(has_nbr, new, labels).astype(labels.dtype)
+    return ell_round_t(labels, nbr.T, wgt.T)
 
 
 def propagate_ell(nbr, wgt, *, rounds: int) -> LabelPropResult:
+    """``rounds`` of LP over node-major ELL adjacency (N, K), run
+    slot-major (one transpose up front)."""
     num_nodes = nbr.shape[0]
     init = jnp.arange(num_nodes, dtype=jnp.int32)
+    nbr_t, wgt_t = nbr.T, wgt.T
 
     def step(labels, _):
-        new = ell_round(labels, nbr, wgt)
+        new = ell_round_t(labels, nbr_t, wgt_t)
         return new, jnp.sum((new != labels).astype(jnp.int32))
 
     labels, changes = lax.scan(step, init, None, length=rounds)

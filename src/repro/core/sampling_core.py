@@ -247,12 +247,12 @@ class SamplerSession:
                             streamed=self._born is not None,
                             engine=self.spec.engine, n=self.num_entities,
                             q=self.num_queries, fused_labels=True) as sp:
-            edges, labels, changes = sharded_graph_and_labels(
+            edges, degrees, labels, changes = sharded_graph_and_labels(
                 self._born if self._born is not None else self.qrels,
                 num_queries=self.num_queries,
                 num_entities=self.num_entities, config=self.spec.to_config(),
                 mesh=self.spec.mesh, axes=self.spec.axes)
-            self._graph = (edges, gb.node_degrees(edges, self.num_entities))
+            self._graph = (edges, degrees)
             self._labels = (labels, changes)
             sp.declare(self._graph, self._labels)
         obs_memory.record_build_peak()

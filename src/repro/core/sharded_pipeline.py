@@ -41,7 +41,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import graph_builder as gb
 from repro.core import label_prop as lp
@@ -82,23 +82,23 @@ def _route_by_query(qrels: gb.QRelTable, *, num_shards: int,
     return gb.QRelTable(q_b, e_b, s_b, v_b)
 
 
-def _local_lp_round(nbr_labels, wgt, own, *, use_kernel: bool):
-    """One LP round on a local node block with pre-gathered neighbour
-    labels — either the jnp reference or the Pallas kernel (hot-loop
-    winner), both bit-identical to label_prop.ell_round."""
+def _local_lp_round(nbr_labels_t, wgt_t, own, *, use_kernel: bool):
+    """One LP round on a local node block with pre-gathered slot-major
+    neighbour labels (K, rows) — either the jnp reference or the Pallas
+    kernel (hot-loop winner), both bit-identical to label_prop.ell_round."""
     if not use_kernel:
-        from repro.kernels.label_prop.ref import label_prop_round_ref
-        return label_prop_round_ref(nbr_labels, wgt, own)
-    from repro.kernels.label_prop.ops import pallas_round_padded
-    return pallas_round_padded(nbr_labels, wgt, own)
+        from repro.kernels.label_prop.ref import round_slot_major
+        return round_slot_major(nbr_labels_t, wgt_t, own)
+    from repro.kernels.label_prop.ops import pallas_round
+    return pallas_round(nbr_labels_t, wgt_t, own)
 
 
 def sharded_graph_and_labels(qrels, *, num_queries: int,
                              num_entities: int, config: WindTunnelConfig,
                              mesh: Mesh, axes: tuple = None) -> tuple:
     """Mesh-partitioned graph build + label propagation (stages 1-3 above):
-    one ``shard_map`` region, returning replicated ``(edges, labels,
-    changes_per_round)``.
+    one ``shard_map`` region, returning ``(edges, degrees, labels,
+    changes_per_round)`` — replicated, or row-sharded on the born path.
 
     ``qrels`` is either a global :class:`~repro.core.graph_builder.
     QRelTable` (tau-filtered and query-routed on device — the legacy flow,
@@ -175,13 +175,14 @@ def sharded_graph_and_labels(qrels, *, num_queries: int,
         # ---- merge: all-gather pair lists, dedup with segment-max ----
         gathered = coll.all_concat(pairs, axes)
         edges = gb.dedup_edges(gathered)
+        degrees = gb.node_degrees(edges, n_pad)
         src, dst, w, e_valid = gb.symmetrize(edges)
 
         # ---- node-partitioned ELL adjacency (local rows only) ----
         row0 = idx * rows_n
         dst_local = dst - row0
         mine = e_valid & (dst_local >= 0) & (dst_local < rows_n)
-        nbr_l, wgt_l = lp.edges_to_ell(
+        nbr_l, wgt_l = lp.edges_to_ell_t(
             src, jnp.where(mine, dst_local, rows_n), w, mine,
             num_nodes=rows_n, max_degree=config.max_degree)
 
@@ -193,10 +194,8 @@ def sharded_graph_and_labels(qrels, *, num_queries: int,
             changed = lax.psum(jnp.sum((new != own).astype(jnp.int32)), axes)
             return lax.all_gather(new, axes, tiled=True), changed
 
-        labels0 = coll.pvary_compat(jnp.arange(n_pad, dtype=jnp.int32), axes)
-        labels, changes = lax.scan(one, labels0, None,
-                                   length=config.lp_rounds)
-        labels = coll.unvary_compat(labels, axes)
+        labels, changes = lax.scan(one, jnp.arange(n_pad, dtype=jnp.int32),
+                                   None, length=config.lp_rounds)
         if born:
             # Born outputs stay row-sharded: every shard computed the SAME
             # replicated edge/label values (dedup of an identical gather;
@@ -210,19 +209,21 @@ def sharded_graph_and_labels(qrels, *, num_queries: int,
             edges = gb.EdgeList(sl(edges.u), sl(edges.v),
                                 sl(edges.w), sl(edges.valid))
             labels = lax.dynamic_slice(labels, (idx * rows_n,), (rows_n,))
-        return edges, labels, changes
+            degrees = lax.dynamic_slice(degrees, (idx * rows_n,), (rows_n,))
+        return edges, degrees, labels, changes
 
     shard_spec = P(axes if len(axes) > 1 else axes[0], None)
     row_spec = P(axes if len(axes) > 1 else axes[0])
     out_edge = (gb.EdgeList(*(row_spec,) * 4) if born
                 else gb.EdgeList(P(), P(), P(), P()))
+    node_spec = row_spec if born else P()
     fn = shard_map(shard_fn, mesh=mesh,
                    in_specs=(shard_spec,) * 4,
-                   out_specs=(out_edge, row_spec if born else P(), P()),
-                   check_rep=False)
-    edges, labels, changes = fn(routed.query_ids, routed.entity_ids,
-                                routed.scores, routed.valid)
-    return edges, labels[:num_entities], changes
+                   out_specs=(out_edge, node_spec, node_spec, P()),
+                   check_vma=False)
+    edges, degrees, labels, changes = fn(routed.query_ids, routed.entity_ids,
+                                         routed.scores, routed.valid)
+    return edges, degrees[:num_entities], labels[:num_entities], changes
 
 
 def run_windtunnel_sharded(qrels: gb.QRelTable, *, num_queries: int,

@@ -13,10 +13,7 @@ helpers cover the patterns we control explicitly:
   primitives for the sharded WindTunnel pipeline (core/sharded_pipeline):
   a tuple of mesh axes treated as one flattened collective axis, with the
   first name most significant — consistent with ``lax.all_gather`` tiled
-  concatenation order over the same tuple;
-* ``pvary_compat`` / ``unvary_compat`` — portability shims for the
-  varying-manual-axes annotations newer JAX requires on replicated
-  ``shard_map`` scan carries (no-ops where ``lax.pvary`` is absent).
+  concatenation order over the same tuple.
 """
 from __future__ import annotations
 
@@ -50,19 +47,6 @@ def all_concat(tree, axis_names: AxisNames):
     axes = _as_tuple(axis_names)
     return jax.tree.map(
         lambda x: lax.all_gather(x, axes, axis=0, tiled=True), tree)
-
-
-def pvary_compat(x, axis_names: AxisNames):
-    """Mark a replicated value device-varying over ``axis_names`` where the
-    installed JAX tracks varying manual axes; identity elsewhere."""
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, _as_tuple(axis_names))
-    return x
-
-
-def unvary_compat(x, axis_names: AxisNames):
-    """Collapse a device-varying-but-equal value back to replicated."""
-    return lax.pmax(x, _as_tuple(axis_names))
 
 
 def psum_scatter_then_gather(x: jnp.ndarray, axis_name: str,

@@ -8,11 +8,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import tuning
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -42,7 +39,7 @@ def flash_attention(q, k, v, q_pos=None, k_pos=None, *, causal: bool = True,
         vp = jnp.pad(vp, ((0, 0), (0, 0), (0, 0), (0, 1)))
     out = flash_attention_pallas(qp, kp, vp, causal=causal, window=window,
                                  block_q=bq, block_kv=bkv,
-                                 interpret=not _on_tpu(),
+                                 interpret=tuning.interpret_mode(),
                                  scale=1.0 / (d ** 0.5))
     if pad_kv and not causal:
         out = out[..., :d]

@@ -1,8 +1,9 @@
-"""Dispatch wrapper: gathers neighbour labels (XLA), pads N to the node
-block, runs the Pallas round kernel (interpret off-TPU).  The node block
-resolves through the autotuner table (kernels/tuning.py): explicit kwarg >
-tuned entry for the row-count bucket > hard-coded default, resolved in the
-plain-python wrappers before any jitted call."""
+"""Dispatch wrappers: gather neighbour labels (XLA), run the Pallas round
+kernel on the slot-major (K, N) layout (interpret mode off-TPU) and keep
+the labels of nodes without neighbours.  The node block resolves through
+the autotuner table (kernels/tuning.py): explicit kwarg > tuned entry for
+the row-count bucket > hard-coded default, resolved in the plain-python
+wrappers before any jitted call."""
 from __future__ import annotations
 
 import functools
@@ -12,47 +13,49 @@ import jax.numpy as jnp
 
 from repro.kernels import tuning
 from repro.kernels.label_prop.label_prop import label_prop_round_pallas
+from repro.kernels.label_prop.ref import keep_isolated, round_slot_major
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def pallas_round_padded(nbr_labels: jnp.ndarray, wgt: jnp.ndarray,
-                        own: jnp.ndarray, *, block_n: int = None):
-    """Run the Pallas round kernel on pre-gathered neighbour labels
-    (N, K), padding N up to the node block; interpret mode off-TPU.
-    Shared by the single-device pallas engine and the sharded pipeline's
-    local node blocks."""
-    rows = nbr_labels.shape[0]
+def pallas_round(nbr_labels_t: jnp.ndarray, wgt_t: jnp.ndarray,
+                 own: jnp.ndarray, *, block_n: int = None):
+    """Run the Pallas round kernel on pre-gathered slot-major neighbour
+    labels (K, N); one block spans N when N fits in it.  Shared by the
+    single-device pallas engine and the sharded pipeline's local node
+    blocks."""
+    rows = nbr_labels_t.shape[1]
     block_n = tuning.resolve("label_prop_round", n=rows, dtype="float32",
                              block_n=block_n)["block_n"]
-    bn = min(block_n, max(8, rows))
-    pad = (-rows) % bn
-    lab_p = jnp.pad(nbr_labels, ((0, pad), (0, 0)), constant_values=-1)
-    wgt_p = jnp.pad(wgt, ((0, pad), (0, 0)))
-    own_p = jnp.pad(own, (0, pad))
-    out = label_prop_round_pallas(lab_p, wgt_p, own_p, block_n=bn,
-                                  interpret=not _on_tpu())
-    return out[:rows]
+    best = label_prop_round_pallas(nbr_labels_t, wgt_t,
+                                   block_n=min(block_n, rows),
+                                   interpret=tuning.interpret_mode())
+    return keep_isolated(best, own)
+
+
+def label_prop_round_t(labels: jnp.ndarray, nbr_t: jnp.ndarray,
+                       wgt_t: jnp.ndarray, *, block_n: int = None,
+                       use_kernel: bool = True):
+    """One LP round over slot-major ELL adjacency: labels (N,), nbr_t
+    (K, N) node ids (-1 pad), wgt_t (K, N). Returns new labels (N,)."""
+    block_n = tuning.resolve("label_prop_round", n=labels.shape[0],
+                             dtype="float32", block_n=block_n)["block_n"]
+    return _label_prop_round(labels, nbr_t, wgt_t, block_n=block_n,
+                             use_kernel=use_kernel)
 
 
 def label_prop_round(labels: jnp.ndarray, nbr: jnp.ndarray,
                      wgt: jnp.ndarray, *, block_n: int = None,
                      use_kernel: bool = True):
-    """One LP round over ELL adjacency: labels (N,), nbr (N, K) node ids
-    (-1 pad), wgt (N, K). Returns new labels (N,)."""
-    block_n = tuning.resolve("label_prop_round", n=labels.shape[0],
-                             dtype="float32", block_n=block_n)["block_n"]
-    return _label_prop_round(labels, nbr, wgt, block_n=block_n,
-                             use_kernel=use_kernel)
+    """One LP round over node-major ELL adjacency: labels (N,), nbr (N, K)
+    node ids (-1 pad), wgt (N, K), transposed to :func:`label_prop_round_t`
+    on every call. Returns new labels (N,)."""
+    return label_prop_round_t(labels, nbr.T, wgt.T, block_n=block_n,
+                              use_kernel=use_kernel)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "use_kernel"))
-def _label_prop_round(labels: jnp.ndarray, nbr: jnp.ndarray,
-                      wgt: jnp.ndarray, *, block_n: int, use_kernel: bool):
-    lab = jnp.where(nbr >= 0, labels[jnp.maximum(nbr, 0)], -1)
+def _label_prop_round(labels: jnp.ndarray, nbr_t: jnp.ndarray,
+                      wgt_t: jnp.ndarray, *, block_n: int, use_kernel: bool):
+    lab = jnp.where(nbr_t >= 0, labels[jnp.maximum(nbr_t, 0)], -1)
     if not use_kernel:
-        from repro.kernels.label_prop.ref import label_prop_round_ref
-        return label_prop_round_ref(lab, wgt, labels)
-    return pallas_round_padded(lab, wgt, labels, block_n=block_n)
+        return round_slot_major(lab, wgt_t, labels)
+    return pallas_round(lab, wgt_t, labels, block_n=block_n)

@@ -1,20 +1,24 @@
 """Pure-jnp oracle for the label_prop kernel — must agree exactly with
-core.label_prop.ell_round (same semantics, same tie-break)."""
+core.label_prop.ell_round (same semantics, same tie-break, same order of
+f32 adds: both score through ``same_label_scores``)."""
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-_I32_MAX = jnp.iinfo(jnp.int32).max
+from repro.kernels.label_prop.label_prop import I32_MAX, best_labels
+
+
+def keep_isolated(best: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
+    """New labels from a round's best labels: nodes without neighbours
+    (``I32_MAX``) keep their current label."""
+    return jnp.where(best == I32_MAX, labels, best).astype(jnp.int32)
+
+
+def round_slot_major(nbr_labels_t, wgt_t, labels):
+    """One round in XLA over slot-major (K, N) neighbour labels/weights."""
+    return keep_isolated(best_labels(nbr_labels_t, wgt_t)[0], labels)
 
 
 def label_prop_round_ref(nbr_labels, wgt, labels):
-    mask = nbr_labels >= 0
-    wm = jnp.where(mask, wgt, 0.0)
-    same = (nbr_labels[:, :, None] == nbr_labels[:, None, :]).astype(jnp.float32)
-    scores = jnp.einsum("nkj,nk->nj", same, wm)
-    scores = jnp.where(mask, scores, -jnp.inf)
-    smax = jnp.max(scores, axis=1, keepdims=True)
-    cand = jnp.where((scores == smax) & mask, nbr_labels, _I32_MAX)
-    best = jnp.min(cand, axis=1)
-    has_nbr = jnp.any(mask, axis=1)
-    return jnp.where(has_nbr, best, labels).astype(jnp.int32)
+    """One round over node-major (N, K) neighbour labels/weights."""
+    return round_slot_major(nbr_labels.T, wgt.T, labels)

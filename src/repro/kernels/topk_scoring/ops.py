@@ -1,6 +1,7 @@
-"""Dispatch wrappers for topk_scoring: pad to block multiples, select
-interpret mode off-TPU, fall back to the jnp oracle for k > 32 (the
-repeated-max extraction stops paying for itself).
+"""Dispatch wrappers for topk_scoring: pad to block multiples and select
+interpret mode off-TPU.  The kernels take any k (their partial tiles widen
+in steps of 128 lanes); the jnp oracle runs only when a caller asks for it
+with ``use_kernel=False``.
 
 Shape contract (the engine path depends on it): any Q/N/C/k combination is
 accepted — k is clamped to the candidate count, inputs are padded to block
@@ -29,16 +30,6 @@ from repro.kernels.topk_scoring.topk_scoring import (gathered_topk_pallas,
                                                      topk_scores_int8_pallas,
                                                      topk_scores_pallas)
 
-_MAX_KERNEL_K = 32
-# the int8 scan exists to feed a float rerank tail of rerank_factor*k
-# candidates (typically 4*k > 32 for the paper's k=10), and its bandwidth
-# win dominates the extra extraction rounds, so its kernel cap is higher
-_MAX_KERNEL_K_INT8 = 64
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
 
 def _ceil8(n: int) -> int:
     return max(8, ((n + 7) // 8) * 8)
@@ -60,7 +51,7 @@ def _topk_scores(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int,
                  block_q: int, block_n: int, use_kernel: bool):
     n = corpus.shape[0]
     k_eff = min(k, n)
-    if not use_kernel or k_eff > _MAX_KERNEL_K:
+    if not use_kernel:
         return _pad_topk(*ref.topk_scores_ref(queries, corpus, k=k_eff), k)
     qn, d = queries.shape
     bq = min(block_q, max(8, qn))
@@ -76,7 +67,7 @@ def _topk_scores(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int,
     if pad_n:
         cp = cp.at[n:, d].set(-1e30)
     s, i = topk_scores_pallas(qp, cp, k=k_eff, block_q=bq, block_n=bn,
-                              interpret=not _on_tpu())
+                              interpret=tuning.interpret_mode())
     if pad_n:
         bad = i >= n
         s = jnp.where(bad, -jnp.inf, s)
@@ -104,7 +95,7 @@ def _topk_scores_int8(q_codes: jnp.ndarray, c_codes: jnp.ndarray, *, k: int,
                       block_q: int, block_n: int, use_kernel: bool):
     n = c_codes.shape[0]
     k_eff = min(k, n)
-    if not use_kernel or k_eff > _MAX_KERNEL_K_INT8:
+    if not use_kernel:
         return _pad_topk(
             *ref.topk_scores_int8_ref(q_codes, c_codes, k=k_eff), k)
     qn = q_codes.shape[0]
@@ -117,7 +108,8 @@ def _topk_scores_int8(q_codes: jnp.ndarray, c_codes: jnp.ndarray, *, k: int,
     qp = jnp.pad(q_codes, ((0, pad_q), (0, 0)))
     cp = jnp.pad(c_codes, ((0, pad_n), (0, 0)))
     s, i = topk_scores_int8_pallas(qp, cp, k=k_eff, block_q=bq, block_n=bn,
-                                   interpret=not _on_tpu(), n_valid=n)
+                                   interpret=tuning.interpret_mode(),
+                                   n_valid=n)
     if pad_n:
         bad = i >= n
         s = jnp.where(bad, -jnp.inf, s)
@@ -146,10 +138,10 @@ def _gathered_topk(queries: jnp.ndarray, cand_vecs: jnp.ndarray,
     qn, d = queries.shape
     c = cand_vecs.shape[1]
     k_eff = min(k, c)
-    if not use_kernel or k_eff > _MAX_KERNEL_K:
+    if not use_kernel:
         return _pad_topk(
             *ref.gathered_topk_ref(queries, cand_vecs, cand_ids, k=k_eff), k)
-    bq = min(block_q, max(1, qn))
+    bq = _ceil8(min(block_q, qn))        # whole sublane tiles of queries
     bc = min(block_c, _ceil8(c))
     pad_q = (-qn) % bq
     pad_c = (-c) % bc
@@ -159,5 +151,5 @@ def _gathered_topk(queries: jnp.ndarray, cand_vecs: jnp.ndarray,
     ip = jnp.pad(cand_ids.astype(jnp.int32), ((0, pad_q), (0, pad_c)),
                  constant_values=-1)
     s, i = gathered_topk_pallas(qp, cp, ip, k=k_eff, block_q=bq, block_c=bc,
-                                interpret=not _on_tpu())
+                                interpret=tuning.interpret_mode())
     return _pad_topk(s[:qn], i[:qn], k)

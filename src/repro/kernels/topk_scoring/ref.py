@@ -1,4 +1,6 @@
-"""Pure-jnp oracle for the topk_scoring kernel."""
+"""Pure-jnp oracle for the topk_scoring kernel.  Float scores use full
+f32 matmul precision, as the kernels do (XLA's TPU default would round
+the operands to bf16)."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -19,7 +21,8 @@ def pad_topk(s: jnp.ndarray, i: jnp.ndarray, k: int):
 
 
 def topk_scores_ref(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int):
-    scores = (queries @ corpus.T).astype(jnp.float32)
+    scores = jnp.dot(queries, corpus.T, precision=lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
     top_s, top_i = lax.top_k(scores, k)
     return top_s, top_i.astype(jnp.int32)
 
@@ -38,7 +41,9 @@ def gathered_topk_ref(queries: jnp.ndarray, cand_vecs: jnp.ndarray,
     """Per-query candidate sets: queries (Q, D), cand_vecs (Q, C, D),
     cand_ids (Q, C) with −1 marking invalid slots -> top-k (scores, ids),
     invalid slots scored −inf and returned as id −1."""
-    s = jnp.einsum("qd,qcd->qc", queries, cand_vecs).astype(jnp.float32)
+    s = jnp.einsum("qd,qcd->qc", queries, cand_vecs,
+                   precision=lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
     s = jnp.where(cand_ids >= 0, s, -jnp.inf)
     top_s, pos = lax.top_k(s, k)
     top_i = jnp.take_along_axis(cand_ids, pos, axis=1).astype(jnp.int32)

@@ -2,13 +2,17 @@
 
 Roofline motivation: brute-force candidate scoring is HBM-bound on the
 (Q, N) score matrix. Fusing the top-k selection into the scoring block keeps
-scores in VMEM and writes only (Q, n_blocks*k) partials back to HBM — an
-N/(n_blocks*k) reduction in output traffic; the final cross-block merge is
-negligible. Candidate blocks stream through VMEM sized by BlockSpec.
+scores in VMEM and writes only one 128-lane tile of partials per
+(query block, candidate block) back to HBM; the final cross-block merge is
+small next to the corpus read. Candidate blocks stream through VMEM sized
+by BlockSpec.
 
-Top-k inside the kernel is k rounds of (max, argmax, mask) on the VMEM
-score block — branch-free VPU code, no sort network needed for the small k
-(<=32) used by ANN probes (paper's p@3 needs k=3).
+Top-k inside the kernel is k rounds of (max, first argmax, mask) on the
+VMEM score block — branch-free VPU code, no sort network. The k partials of
+a block land in the first k lanes of a ``tile_width(k)``-lane output tile
+(the rest stay −inf / −1); the wrappers slice them back out before the
+merge. Every block is (8, 128)-aligned or spans the whole array dim, which
+is what the TPU compiler requires of a block's last two dims.
 """
 from __future__ import annotations
 
@@ -19,35 +23,68 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+LANES = 128
+# f32 scoring runs at full f32 precision on the MXU (the XLA reference
+# backends ask for the same), so kernel and reference rank alike
+_F32_DOT = lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))      # contract the last dim of both operands
 
-def _topk_kernel(q_ref, c_ref, s_out_ref, i_out_ref, *, k: int, block_n: int):
-    j = pl.program_id(1)                       # candidate-block index
-    q = q_ref[...]                             # (bq, d)
-    c = c_ref[...]                             # (bn, d)
-    scores = jnp.dot(q, c.T,
-                     preferred_element_type=jnp.float32)   # (bq, bn) in VMEM
-    bq = scores.shape[0]
+
+def tile_width(k: int) -> int:
+    """Lane width of one block's partial top-k tile: k rounded up to 128."""
+    return -(-k // LANES) * LANES
+
+
+def extract_topk(scores, ids_of, *, k: int, width: int):
+    """k rounds of (max, first argmax, mask) over a (bq, bn) score block.
+
+    ``ids_of(hit, arg)`` maps the one-hot column mask / column index of
+    each row's current maximum to the id written out.  Returns
+    (scores (bq, width), ids (bq, width)): round i fills lane i, lanes past
+    k stay −inf / −1.  Equal scores extract in ascending column order."""
+    bq, bn = scores.shape
+    col = lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
+    lane = lax.broadcasted_iota(jnp.int32, (bq, width), 1)
 
     def body(i, carry):
         scores, out_s, out_i = carry
-        m = jnp.max(scores, axis=1)                        # (bq,)
-        arg = jnp.argmax(scores, axis=1).astype(jnp.int32)  # (bq,)
-        out_s = lax.dynamic_update_slice(out_s, m[:, None], (0, i))
-        out_i = lax.dynamic_update_slice(
-            out_i, (j * block_n + arg)[:, None], (0, i))
-        # mask the extracted maximum for the next round
-        hit = lax.broadcasted_iota(jnp.int32, scores.shape, 1) == arg[:, None]
+        m = jnp.max(scores, axis=1, keepdims=True)                 # (bq, 1)
+        arg = jnp.min(jnp.where(scores == m, col, bn), axis=1,
+                      keepdims=True)                               # (bq, 1)
+        hit = col == arg
+        out_s = jnp.where(lane == i, m, out_s)
+        out_i = jnp.where(lane == i, ids_of(hit, arg), out_i)
         return jnp.where(hit, -jnp.inf, scores), out_s, out_i
 
-    out_s = jnp.full((bq, k), -jnp.inf, jnp.float32)
-    out_i = jnp.full((bq, k), -1, jnp.int32)
+    out_s = jnp.full((bq, width), -jnp.inf, jnp.float32)
+    out_i = jnp.full((bq, width), -1, jnp.int32)
     _, out_s, out_i = lax.fori_loop(0, k, body, (scores, out_s, out_i))
-    s_out_ref[...] = out_s
-    i_out_ref[...] = out_i
+    return out_s, out_i
+
+
+def merge_partials(partial_s, partial_i, *, k: int, width: int):
+    """Cross-block merge: keep the first k lanes of every block's tile
+    (block-major, so ties still favour the earlier block) and take the
+    global top-k."""
+    qn = partial_s.shape[0]
+    s = partial_s.reshape(qn, -1, width)[:, :, :k].reshape(qn, -1)
+    i = partial_i.reshape(qn, -1, width)[:, :, :k].reshape(qn, -1)
+    top_s, pos = lax.top_k(s, k)
+    return top_s, jnp.take_along_axis(i, pos, axis=1)
+
+
+def _topk_kernel(q_ref, c_ref, s_out_ref, i_out_ref, *, k: int, width: int,
+                 block_n: int):
+    j = pl.program_id(1)                       # candidate-block index
+    scores = lax.dot_general(q_ref[...], c_ref[...], _NT,
+                             precision=_F32_DOT,
+                             preferred_element_type=jnp.float32)  # (bq, bn)
+    s_out_ref[...], i_out_ref[...] = extract_topk(
+        scores, lambda hit, arg: j * block_n + arg, k=k, width=width)
 
 
 def _topk_int8_kernel(q_ref, c_ref, s_out_ref, i_out_ref, *, k: int,
-                      block_n: int, n_valid: int):
+                      width: int, block_n: int, n_valid: int):
     """int8 variant: codes dot in int8 with an int32 accumulator (the MXU's
     quantized path on TPU), ranking on the raw integer dot — the global
     query/corpus scales are positive constants, so the int32 order equals
@@ -55,62 +92,48 @@ def _topk_int8_kernel(q_ref, c_ref, s_out_ref, i_out_ref, *, k: int,
     (``n_valid``), the lsh kernel's scheme — an int8 sentinel coordinate
     can't work, the widest code is ±127."""
     j = pl.program_id(1)
-    q = q_ref[...]                             # (bq, d) int8
-    c = c_ref[...]                             # (bn, d) int8
-    scores = lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32).astype(jnp.float32)
+    scores = lax.dot_general(q_ref[...], c_ref[...], _NT,
+                             preferred_element_type=jnp.int32
+                             ).astype(jnp.float32)
     ids = j * block_n + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     scores = jnp.where(ids < n_valid, scores, -jnp.inf)
-    bq = scores.shape[0]
-
-    def body(i, carry):
-        scores, out_s, out_i = carry
-        m = jnp.max(scores, axis=1)
-        arg = jnp.argmax(scores, axis=1).astype(jnp.int32)
-        out_s = lax.dynamic_update_slice(out_s, m[:, None], (0, i))
-        out_i = lax.dynamic_update_slice(
-            out_i, (j * block_n + arg)[:, None], (0, i))
-        hit = lax.broadcasted_iota(jnp.int32, scores.shape, 1) == arg[:, None]
-        return jnp.where(hit, -jnp.inf, scores), out_s, out_i
-
-    out_s = jnp.full((bq, k), -jnp.inf, jnp.float32)
-    out_i = jnp.full((bq, k), -1, jnp.int32)
-    _, out_s, out_i = lax.fori_loop(0, k, body, (scores, out_s, out_i))
-    s_out_ref[...] = out_s
-    i_out_ref[...] = out_i
+    s_out_ref[...], i_out_ref[...] = extract_topk(
+        scores, lambda hit, arg: j * block_n + arg, k=k, width=width)
 
 
-def _gathered_kernel(q_ref, c_ref, i_ref, s_out_ref, i_out_ref, *, k: int):
+def _gathered_kernel(q_ref, c_ref, i_ref, s_out_ref, i_out_ref, *, k: int,
+                     width: int):
     """Per-query candidate scoring: each query row scores ITS OWN candidate
-    block (the ivfflat probe gather), so the dot is a batched row-wise
-    reduction on the VPU rather than an MXU matmul; the running top-k is the
-    same k-round max/mask extraction as _topk_kernel."""
+    block (the ivfflat probe gather), a batched (1, d) x (bc, d) product per
+    query on the MXU; the running top-k is the same extraction as
+    _topk_kernel, with ids read from the candidate-id block."""
     q = q_ref[...]                              # (bq, d)
     c = c_ref[...]                              # (bq, bc, d)
     ids = i_ref[...]                            # (bq, bc) int32, -1 invalid
-    scores = jnp.sum(q[:, None, :] * c, axis=-1,
-                     dtype=jnp.float32)         # (bq, bc)
-    scores = jnp.where(ids >= 0, scores, -jnp.inf)
-
-    def body(i, carry):
-        scores, out_s, out_i = carry
-        m = jnp.max(scores, axis=1)
-        arg = jnp.argmax(scores, axis=1).astype(jnp.int32)
-        col = lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        hit = col == arg[:, None]
-        # id extraction without a dynamic gather: mask-select the argmax col
-        idv = jnp.sum(jnp.where(hit, ids, 0), axis=1)
-        idv = jnp.where(jnp.isfinite(m), idv, -1)
-        out_s = lax.dynamic_update_slice(out_s, m[:, None], (0, i))
-        out_i = lax.dynamic_update_slice(out_i, idv[:, None], (0, i))
-        return jnp.where(hit, -jnp.inf, scores), out_s, out_i
-
-    out_s = jnp.full((q.shape[0], k), -jnp.inf, jnp.float32)
-    out_i = jnp.full((q.shape[0], k), -1, jnp.int32)
-    _, out_s, out_i = lax.fori_loop(0, k, body, (scores, out_s, out_i))
+    bq, bc, _ = c.shape
+    scores = lax.dot_general(q[:, None, :], c, (((2,), (2,)), ((0,), (0,))),
+                             precision=_F32_DOT,
+                             preferred_element_type=jnp.float32)
+    scores = jnp.where(ids >= 0, scores.reshape(bq, bc), -jnp.inf)
+    # id extraction without a dynamic gather: mask-select the argmax col
+    out_s, out_i = extract_topk(
+        scores, lambda hit, arg: jnp.sum(jnp.where(hit, ids, 0), axis=1,
+                                         keepdims=True), k=k, width=width)
     s_out_ref[...] = out_s
-    i_out_ref[...] = out_i
+    i_out_ref[...] = jnp.where(jnp.isfinite(out_s), out_i, -1)
+
+
+def _partials_call(kernel, grid, in_specs, args, *, qn: int, nc: int,
+                   block_q: int, width: int, interpret: bool):
+    """pallas_call with the shared (Q, nc * width) partial-tile outputs."""
+    out_spec = pl.BlockSpec((block_q, width), lambda i, j: (i, j))
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs,
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((qn, nc * width), jnp.float32),
+                   jax.ShapeDtypeStruct((qn, nc * width), jnp.int32)],
+        interpret=interpret,
+    )(*args)
 
 
 @functools.partial(jax.jit,
@@ -126,28 +149,16 @@ def gathered_topk_pallas(queries: jnp.ndarray, cand_vecs: jnp.ndarray,
     qn, d = queries.shape
     c = cand_vecs.shape[1]
     nq, nc = qn // block_q, c // block_c
-
-    partial_s, partial_i = pl.pallas_call(
-        functools.partial(_gathered_kernel, k=k),
-        grid=(nq, nc),
-        in_specs=[
-            pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, block_c, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((block_q, block_c), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_q, k), lambda i, j: (i, j)),
-            pl.BlockSpec((block_q, k), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qn, nc * k), jnp.float32),
-            jax.ShapeDtypeStruct((qn, nc * k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(queries, cand_vecs, cand_ids)
-
-    top_s, pos = lax.top_k(partial_s, k)
-    top_i = jnp.take_along_axis(partial_i, pos, axis=1)
+    width = tile_width(k)
+    partial_s, partial_i = _partials_call(
+        functools.partial(_gathered_kernel, k=k, width=width),
+        (nq, nc),
+        [pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
+         pl.BlockSpec((block_q, block_c, d), lambda i, j: (i, j, 0)),
+         pl.BlockSpec((block_q, block_c), lambda i, j: (i, j))],
+        (queries, cand_vecs, cand_ids), qn=qn, nc=nc, block_q=block_q,
+        width=width, interpret=interpret)
+    top_s, top_i = merge_partials(partial_s, partial_i, k=k, width=width)
     return top_s, jnp.where(jnp.isfinite(top_s), top_i, -1)
 
 
@@ -164,29 +175,15 @@ def topk_scores_pallas(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int,
     qn, d = queries.shape
     n = corpus.shape[0]
     nq, nc = qn // block_q, n // block_n
-
-    partial_s, partial_i = pl.pallas_call(
-        functools.partial(_topk_kernel, k=k, block_n=block_n),
-        grid=(nq, nc),
-        in_specs=[
-            pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_q, k), lambda i, j: (i, j)),
-            pl.BlockSpec((block_q, k), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qn, nc * k), jnp.float32),
-            jax.ShapeDtypeStruct((qn, nc * k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(queries, corpus)
-
-    # cross-block merge of the (nc * k) partials per query
-    top_s, pos = lax.top_k(partial_s, k)
-    top_i = jnp.take_along_axis(partial_i, pos, axis=1)
-    return top_s, top_i
+    width = tile_width(k)
+    partial_s, partial_i = _partials_call(
+        functools.partial(_topk_kernel, k=k, width=width, block_n=block_n),
+        (nq, nc),
+        [pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
+         pl.BlockSpec((block_n, d), lambda i, j: (j, 0))],
+        (queries, corpus), qn=qn, nc=nc, block_q=block_q, width=width,
+        interpret=interpret)
+    return merge_partials(partial_s, partial_i, k=k, width=width)
 
 
 @functools.partial(jax.jit,
@@ -204,26 +201,14 @@ def topk_scores_int8_pallas(q_codes: jnp.ndarray, c_codes: jnp.ndarray, *,
     qn, d = q_codes.shape
     n = c_codes.shape[0]
     nq, nc = qn // block_q, n // block_n
-
-    partial_s, partial_i = pl.pallas_call(
-        functools.partial(_topk_int8_kernel, k=k, block_n=block_n,
+    width = tile_width(k)
+    partial_s, partial_i = _partials_call(
+        functools.partial(_topk_int8_kernel, k=k, width=width,
+                          block_n=block_n,
                           n_valid=n if n_valid is None else n_valid),
-        grid=(nq, nc),
-        in_specs=[
-            pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_q, k), lambda i, j: (i, j)),
-            pl.BlockSpec((block_q, k), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qn, nc * k), jnp.float32),
-            jax.ShapeDtypeStruct((qn, nc * k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(q_codes, c_codes)
-
-    top_s, pos = lax.top_k(partial_s, k)
-    top_i = jnp.take_along_axis(partial_i, pos, axis=1)
-    return top_s, top_i
+        (nq, nc),
+        [pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
+         pl.BlockSpec((block_n, d), lambda i, j: (j, 0))],
+        (q_codes, c_codes), qn=qn, nc=nc, block_q=block_q, width=width,
+        interpret=interpret)
+    return merge_partials(partial_s, partial_i, k=k, width=width)

@@ -29,15 +29,21 @@ via :func:`resolve`):
 
   explicit kwarg  >  tuned table entry  >  hard-coded default
 
-The active table resolves once per process from, in order: the
+The active table resolves once per process from the
 ``REPRO_TUNED_KERNELS`` env var (``off``/``0``/``none`` forces the
 hard-coded defaults everywhere — the escape hatch; any other value is a
-table path), else ``results/tuned_kernels.json`` when present, else the
-checked-in default table.  ``set_table``/``reset_table`` override it in
-process (tests, the ``--no-tuned-kernels`` CLI flags).  Note block
-resolution happens when a consumer traces, so jitted callers that cached a
-trace keep the blocks they were traced with until their jit cache is
-cleared.
+table path), else the checked-in default table.  Nothing else on disk is
+read implicitly: ``autotune`` writes ``results/tuned_kernels.json`` and
+activates it in its own process; a later process loads it only through
+``REPRO_TUNED_KERNELS``.  ``set_table``/``reset_table`` override it in
+process (tests, the ``--no-tuned-kernels`` CLI flags).
+
+A table applies only on the device kind it was tuned on
+(``meta["device_kind"]`` equal to the running device's): blocks chosen
+for one device say nothing about another, so elsewhere every kernel takes
+the hard-coded :data:`DEFAULTS`.  Note block resolution happens when a
+consumer traces, so jitted callers that cached a trace keep the blocks
+they were traced with until their jit cache is cleared.
 """
 from __future__ import annotations
 
@@ -163,11 +169,11 @@ SPACES: Dict[str, TuningSpace] = {
         "block_n": (128, 256, 512, 1024, 2048),
     }),
     "gathered_topk": TuningSpace("gathered_topk", {
-        "block_q": (1, 4, 8, 16),
+        "block_q": (8, 16, 32),
         "block_c": (128, 256, 512, 1024),
     }),
     "label_prop_round": TuningSpace("label_prop_round", {
-        "block_n": (64, 128, 256, 512, 1024),
+        "block_n": (128, 256, 512, 1024, 2048),
     }),
 }
 
@@ -293,10 +299,10 @@ def _bench_call(kernel: str, n: int, dtype: str):
         from repro.kernels.lsh_hamming import ops as lsh_ops
         q = jax.random.randint(key, (64, 4), -2**31, 2**31 - 1,
                                dtype=jnp.int32)
-        c = jax.random.randint(jax.random.PRNGKey(1), (n, 4), -2**31,
+        c = jax.random.randint(jax.random.PRNGKey(1), (4, n), -2**31,
                                2**31 - 1, dtype=jnp.int32)
         return (q, c), lambda pt: (
-            lambda a, b: lsh_ops.hamming_topk(a, b, k=8, **pt))
+            lambda a, b: lsh_ops.hamming_topk_t(a, b, k=8, **pt))
     if kernel == "gathered_topk":
         from repro.kernels.topk_scoring import ops as topk_ops
         c = min(n, 4096)      # candidates per query (nprobe * cap scale)
@@ -308,11 +314,11 @@ def _bench_call(kernel: str, n: int, dtype: str):
             lambda a, b, i: topk_ops.gathered_topk(a, b, i, k=8, **pt))
     if kernel == "label_prop_round":
         from repro.kernels.label_prop import ops as lp_ops
-        nbr = jax.random.randint(key, (n, 16), -1, n, dtype=jnp.int32)
-        wgt = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (n, 16)))
+        nbr = jax.random.randint(key, (16, n), -1, n, dtype=jnp.int32)
+        wgt = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (16, n)))
         labels = jnp.arange(n, dtype=jnp.int32)
         return (labels, nbr, wgt), lambda pt: (
-            lambda lb, nb, w: lp_ops.label_prop_round(lb, nb, w, **pt))
+            lambda lb, nb, w: lp_ops.label_prop_round_t(lb, nb, w, **pt))
     raise ValueError(f"unknown kernel primitive {kernel!r}; "
                      f"tunable: {', '.join(sorted(SPACES))}")
 
@@ -429,8 +435,6 @@ def _load_active() -> TunedTable:
         if env.strip().lower() in ("", "0", "off", "none"):
             return TunedTable()          # escape hatch: hard-coded defaults
         return TunedTable.load(env)
-    if os.path.exists(RESULTS_TABLE_PATH):
-        return TunedTable.load(RESULTS_TABLE_PATH)
     if os.path.exists(DEFAULT_TABLE_PATH):
         return TunedTable.load(DEFAULT_TABLE_PATH)
     return TunedTable()
@@ -453,9 +457,27 @@ def reset_table() -> None:
     _ACTIVE.clear()
 
 
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in the interpreter: everywhere but on
+    a TPU, where they compile natively.  Read at trace time by every
+    ``kernels/*/ops.py`` wrapper."""
+    import jax
+    return jax.default_backend() != "tpu"
+
+
+def device_kind() -> str:
+    """``device_kind`` of the device the kernels dispatch to."""
+    import jax
+    return jax.devices()[0].device_kind
+
+
 def lookup(kernel: str, *, n: int, dtype: Any) -> Dict[str, int]:
-    """Tuned params for an n-row call, or {} when none recorded."""
-    return get_table().lookup(kernel, size_bucket(n), dtype_str(dtype))
+    """Tuned params for an n-row call, or {} when none recorded or when
+    the active table was tuned on another device kind."""
+    table = get_table()
+    if table.meta.get("device_kind") != device_kind():
+        return {}
+    return table.lookup(kernel, size_bucket(n), dtype_str(dtype))
 
 
 # Observability (DESIGN.md §12): every resolve() bumps the tuned-table

@@ -316,7 +316,7 @@ def build_recsys_cell(arch_id, shape_name, mesh, *, reduced=False,
             # sharded-ANN layout), so the 512MB cross-shard row
             # gather/all-reduce disappears; only (grid x k) merge payloads
             # cross the wire.
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             u = rs.user_vector(params, batch, cfg)          # (B, D) replicated
             items = rs.item_matrix(params, cfg)             # rows grid-sharded
 
@@ -344,7 +344,7 @@ def build_recsys_cell(arch_id, shape_name, mesh, *, reduced=False,
             # §Perf lever: per-shard local top-k then merge — the global
             # lax.top_k over a model-sharded axis otherwise all-gathers the
             # full (B, n_candidates) score matrix
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
 
             def local_topk(s):
                 ls, li = jax.lax.top_k(s, k_top)

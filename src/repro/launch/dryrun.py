@@ -28,6 +28,7 @@ import jax
 
 from repro.configs import get_arch, iter_cells, list_archs
 from repro.launch.cells import build_cell
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.logs import add_logging_args, setup_logging
 from repro.launch.mesh import make_production_mesh
 # the hardware constants live at the bottom of the stack (kernels/tuning.py)
@@ -144,6 +145,7 @@ def main(argv=None):
     add_logging_args(p)
     args = p.parse_args(argv)
     setup_logging(args)
+    enable_compile_cache()
 
     meshes = []
     if args.mesh in ("single", "both"):

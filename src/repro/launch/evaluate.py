@@ -25,6 +25,7 @@ from repro.eval import (GridSpec, SearchConfig, available_backends,
                         get_backend, get_retrieval_engine, get_sampler,
                         run_grid)
 from repro.kernels import tuning
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.logs import (add_logging_args, add_obs_args, init_obs,
                                setup_logging, write_metrics)
 from repro.launch.mesh import parse_mesh
@@ -96,6 +97,7 @@ def main(argv=None):
     args = p.parse_args(argv)
     setup_logging(args)
     init_obs(args)
+    enable_compile_cache()
 
     spec = GRIDS[args.grid]
     overrides = {}

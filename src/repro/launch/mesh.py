@@ -11,22 +11,33 @@ The 'model' axis carries TP/EP/vocab sharding (highest-bandwidth inner
 axis); 'data' carries DP + ZeRO-sharded parameter/optimizer state; 'pod'
 carries pure DP whose gradient all-reduce crosses the DCI links — that is
 the all-reduce gradient compression (distributed/compression.py) targets.
+
+Every mesh here has Auto axes: the model code places activations with
+``with_sharding_constraint`` and leaves the rest to the SPMD partitioner,
+which Explicit axes (``jax.make_mesh``'s default) refuse.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types (see the module docstring)."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
     """Degenerate 1-device mesh with the production axis NAMES, so the same
     sharded step functions run in smoke tests on CPU."""
-    return jax.make_mesh((1, model_axis), ("data", "model"))
+    return make_mesh((1, model_axis), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:
@@ -42,5 +53,5 @@ def parse_mesh(name: str):
     if name == "host":
         return make_host_mesh()
     if name == "auto":
-        return jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+        return make_mesh((len(jax.devices()), 1), ("data", "model"))
     raise ValueError(f"unknown mesh {name!r}; known meshes: auto, host")

@@ -33,6 +33,7 @@ from repro.core import (QRelTable, SamplerSession, SamplerSpec,
                         get_sampler)
 from repro.core.engines import get_engine
 from repro.data.synthetic import generate_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.logs import (add_logging_args, add_obs_args, init_obs,
                                setup_logging, write_metrics)
 from repro.launch.mesh import parse_mesh
@@ -92,6 +93,7 @@ def main(argv=None):
     args = p.parse_args(argv)
     setup_logging(args)
     init_obs(args)
+    enable_compile_cache()
     # unknown names fail with the registry's error message before any
     # corpus work — the same error contract as launch/evaluate.py
     get_sampler(args.strategy)

@@ -32,6 +32,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.logs import (add_logging_args, add_obs_args, init_obs,
                                setup_logging, write_metrics)
 from repro.obs import recompile
@@ -157,6 +158,7 @@ def main(argv: Optional[list] = None) -> int:
     args = p.parse_args(argv)
     setup_logging(args)
     init_obs(args)
+    enable_compile_cache()
     if args.recompile_check > 0:
         recompile.enable()
     # fail fast with the registry error messages, before any build

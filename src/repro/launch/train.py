@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.configs import get_arch, list_archs
 from repro.launch.cells import build_cell
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.train.loop import LoopConfig, train_loop
 from repro.train.optimizer import adamw_init
@@ -40,6 +41,7 @@ def main(argv=None):
     p.add_argument("--checkpoint-dir", default=None)
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     spec = get_arch(args.arch)
     shape = args.shape or next(s for s, v in spec.shapes.items()

@@ -25,38 +25,31 @@ import jax
 
 from repro.obs.metrics import REGISTRY, Registry
 
-__all__ = ["PEAK_GAUGE", "bytes_per_device", "record_build_peak"]
+__all__ = ["PEAK_GAUGE", "bytes_per_device", "record_build_peak",
+           "resident_bytes_per_device"]
 
 #: gauge name for the per-device build high-water mark
 PEAK_GAUGE = "build.peak_bytes_per_device"
 
 
-def _allocator_stats(device) -> Optional[int]:
-    try:
-        stats = device.memory_stats()
-    except Exception:  # platform without allocator stats (CPU)
-        return None
-    if not stats:
-        return None
-    for key in ("peak_bytes_in_use", "bytes_in_use"):
-        if key in stats:
-            return int(stats[key])
-    return None
-
-
-def bytes_per_device() -> Dict[str, int]:
-    """device -> resident bytes: allocator peak where available, live-array
-    shard accounting otherwise."""
-    devices = jax.local_devices()
+def _allocator_bytes(devices, keys) -> Optional[Dict[str, int]]:
+    """device -> the first of ``keys`` its allocator reports, or None when
+    any device reports none of them (CPU)."""
     per = {}
     for dev in devices:
-        val = _allocator_stats(dev)
+        try:
+            stats = dev.memory_stats() or {}
+        except Exception:  # platform without allocator stats (CPU)
+            return None
+        val = next((stats[k] for k in keys if k in stats), None)
         if val is None:
-            break
-        per[str(dev)] = val
-    else:
-        return per
-    # fallback: sum the addressable shards of every live array per device
+            return None
+        per[str(dev)] = int(val)
+    return per
+
+
+def _live_array_bytes(devices) -> Dict[str, int]:
+    """device -> summed bytes of the addressable shards of live arrays."""
     per = {str(dev): 0 for dev in devices}
     for arr in jax.live_arrays():
         try:
@@ -68,6 +61,24 @@ def bytes_per_device() -> Dict[str, int]:
             if key in per and sh.data is not None:
                 per[key] += int(sh.data.nbytes)
     return per
+
+
+def bytes_per_device() -> Dict[str, int]:
+    """device -> resident bytes: allocator peak where available, live-array
+    shard accounting otherwise."""
+    devices = jax.local_devices()
+    return (_allocator_bytes(devices, ("peak_bytes_in_use", "bytes_in_use"))
+            or _live_array_bytes(devices))
+
+
+def resident_bytes_per_device() -> Dict[str, int]:
+    """device -> bytes held right now: the allocator's ``bytes_in_use``
+    where available, live-array shard accounting otherwise.  Unlike the
+    peak it falls when arrays are freed, so two readings bracket what a
+    stage left resident."""
+    devices = jax.local_devices()
+    return (_allocator_bytes(devices, ("bytes_in_use",))
+            or _live_array_bytes(devices))
 
 
 def record_build_peak(registry: Registry = REGISTRY) -> int:

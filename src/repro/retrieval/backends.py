@@ -42,6 +42,10 @@ string on any engine rather than a fork in each index.  Registered:
 matrix through it so a backend can transform the layout once per index
 (identity for jnp/pallas, quantization for int8).
 
+Float scores are computed at full f32 matmul precision on every backend
+(``lax.Precision.HIGHEST``; XLA's TPU default rounds f32 operands to bf16),
+so the backends rank alike on the chip as they do on the CPU.
+
 Tie policy (both backends, verified by tests/test_search_core.py): results
 are score-descending; equal scores break toward the FIRST candidate in the
 input layout (``lax.top_k`` takes the first occurrence, and the kernels'
@@ -67,7 +71,7 @@ from jax import lax
 
 from repro.distributed.compression import quantize_int8
 from repro.kernels.lsh_hamming import ops as lsh_ops
-from repro.kernels.lsh_hamming.ref import hamming_topk_ref
+from repro.kernels.lsh_hamming.ref import hamming_topk_ref_t
 from repro.kernels.topk_scoring import ops as topk_ops
 from repro.kernels.topk_scoring.ref import gathered_topk_ref
 from repro.kernels.topk_scoring.ref import pad_topk as _pad_topk
@@ -88,9 +92,10 @@ class ScoringBackend(Protocol):
         """(Q, D) x prepared corpus -> (scores f32[Q, k], ids i32[Q, k])."""
         ...
 
-    def hamming_topk(self, q_codes: jnp.ndarray, c_codes: jnp.ndarray, *,
+    def hamming_topk(self, q_codes: jnp.ndarray, c_codes_t: jnp.ndarray, *,
                      k: int):
-        """Packed codes (Q, W) x (N, W) -> (−distance f32[Q, k], ids)."""
+        """Packed codes (Q, W) x the index's transposed codes (W, N) ->
+        (−distance f32[Q, k], ids)."""
         ...
 
     def gathered_topk(self, queries: jnp.ndarray, cand_vecs: jnp.ndarray,
@@ -146,7 +151,8 @@ def rerank_candidates(vecs: jnp.ndarray, queries: jnp.ndarray,
     lsh search paths and the int8 backend's float tail, so all rank
     identically."""
     cvecs = vecs[jnp.maximum(cand, 0)]                    # (Q, R, d)
-    s = jnp.einsum("qd,qrd->qr", queries, cvecs)
+    s = jnp.einsum("qd,qrd->qr", queries, cvecs,
+                   precision=lax.Precision.HIGHEST)
     s = jnp.where(cand >= 0, s, -jnp.inf)
     top_s, pos = lax.top_k(s, min(k, cand.shape[1]))
     top_i = jnp.take_along_axis(cand, pos, axis=1)
@@ -171,7 +177,8 @@ def _blocked_topk(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int,
     def step(carry, xs):
         best_s, best_i = carry
         blk, bi = xs
-        s = (queries @ blk.T).astype(jnp.float32)             # (Q, block)
+        s = jnp.dot(queries, blk.T, precision=lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)        # (Q, block)
         ids = bi * block + jnp.arange(block, dtype=jnp.int32)[None]
         valid = ids < n
         s = jnp.where(valid, s, -jnp.inf)
@@ -204,9 +211,9 @@ class JnpBackend:
         return _blocked_topk(queries, _float_corpus(corpus), k=k,
                              block=self.block)
 
-    def hamming_topk(self, q_codes, c_codes, *, k: int):
-        k_eff = min(k, c_codes.shape[0])
-        return _pad_topk(*hamming_topk_ref(q_codes, c_codes, k=k_eff), k)
+    def hamming_topk(self, q_codes, c_codes_t, *, k: int):
+        k_eff = min(k, c_codes_t.shape[1])
+        return _pad_topk(*hamming_topk_ref_t(q_codes, c_codes_t, k=k_eff), k)
 
     def gathered_topk(self, queries, cand_vecs, cand_ids, *, k: int):
         k_eff = min(k, cand_ids.shape[1])
@@ -218,7 +225,7 @@ class JnpBackend:
 @dataclasses.dataclass(frozen=True)
 class PallasBackend:
     """Fused Pallas kernels (interpret mode off-TPU); the dispatch wrappers
-    in kernels/*/ops.py own padding, k-clamping and the k > 32 fallback.
+    in kernels/*/ops.py own padding and k-clamping.
 
     ``None`` block fields defer to the autotuner table (kernels/tuning.py):
     explicit kwarg > tuned entry for the corpus-size bucket > hard-coded
@@ -237,10 +244,10 @@ class PallasBackend:
                                     block_q=self.block_q,
                                     block_n=self.block_n)
 
-    def hamming_topk(self, q_codes, c_codes, *, k: int):
-        return lsh_ops.hamming_topk(q_codes, c_codes, k=k,
-                                    block_q=self.block_q,
-                                    block_n=self.block_n)
+    def hamming_topk(self, q_codes, c_codes_t, *, k: int):
+        return lsh_ops.hamming_topk_t(q_codes, c_codes_t, k=k,
+                                      block_q=self.block_q,
+                                      block_n=self.block_n)
 
     def gathered_topk(self, queries, cand_vecs, cand_ids, *, k: int):
         return topk_ops.gathered_topk(queries, cand_vecs, cand_ids, k=k,
@@ -285,10 +292,10 @@ class Int8Backend:
                                             block_n=self.block_n)
         return rerank_candidates(qc.vecs, queries, cand, k=k)
 
-    def hamming_topk(self, q_codes, c_codes, *, k: int):
-        return lsh_ops.hamming_topk(q_codes, c_codes, k=k,
-                                    block_q=self.block_q,
-                                    block_n=self.block_n)
+    def hamming_topk(self, q_codes, c_codes_t, *, k: int):
+        return lsh_ops.hamming_topk_t(q_codes, c_codes_t, k=k,
+                                      block_q=self.block_q,
+                                      block_n=self.block_n)
 
     def gathered_topk(self, queries, cand_vecs, cand_ids, *, k: int):
         return topk_ops.gathered_topk(queries, cand_vecs, cand_ids, k=k)

@@ -130,14 +130,12 @@ class IVFFlatEngine:
 
     n_lists: int = 64
     nprobe: int = 8
-    cap_factor: float = 2.0
     backend: str = "jnp"
     name: str = "ivfflat"
 
     def build(self, key, vecs):
         n_lists = min(self.n_lists, max(1, vecs.shape[0] // 8))
-        return build_ivfflat(key, vecs, n_lists=n_lists,
-                             cap_factor=self.cap_factor)
+        return build_ivfflat(key, vecs, n_lists=n_lists)
 
     def search(self, index, queries, *, k: int):
         return self.search_scored(index, queries, k=k)[1]
@@ -166,7 +164,7 @@ class LSHEngine:
         return self.search_scored(index, queries, k=k)[1]
 
     def search_scored(self, index, queries, *, k: int):
-        n = index.codes.shape[0]
+        n = index.vecs.shape[0]
         rerank = min(max(self.rerank, k), n) if self.rerank > 0 else 0
         return search_lsh(index, queries, k=k, rerank=rerank,
                           backend=self.backend)

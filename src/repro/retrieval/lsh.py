@@ -14,6 +14,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels.topk_scoring.ref import pad_topk  # noqa: F401 (re-export)
 from repro.retrieval.backends import get_backend, rerank_candidates
@@ -21,7 +22,8 @@ from repro.retrieval.backends import get_backend, rerank_candidates
 
 class LSHIndex(NamedTuple):
     proj: jnp.ndarray    # (d, n_bits) random projection
-    codes: jnp.ndarray   # (N, n_words) packed int32
+    codes: jnp.ndarray   # (n_words, N) packed int32, transposed once at
+                         # build so the Hamming kernel reads it as it is
     vecs: jnp.ndarray    # (N, d) kept for rerank
 
 
@@ -44,13 +46,16 @@ def popcount32(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def encode(proj: jnp.ndarray, vecs: jnp.ndarray) -> jnp.ndarray:
-    return _pack_bits((vecs @ proj) > 0)
+    # full f32 projection: XLA's TPU default (bf16 operands) would flip
+    # the sign bits of near-zero projections
+    return _pack_bits(jnp.dot(vecs, proj, precision=lax.Precision.HIGHEST)
+                      > 0)
 
 
 def build_lsh(key, corpus: jnp.ndarray, *, n_bits: int = 128) -> LSHIndex:
     d = corpus.shape[1]
     proj = jax.random.normal(key, (d, n_bits), corpus.dtype)
-    return LSHIndex(proj, encode(proj, corpus), corpus)
+    return LSHIndex(proj, encode(proj, corpus).T, corpus)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "rerank", "backend"))

@@ -30,10 +30,11 @@ Partition plans per engine (both generations share the merge):
     top-k via ``backend.topk``; global ids recovered from the shard's row
     offset.  Born tfidf reduces the document-frequency vector with an
     integer ``psum`` (bit-identical IDF weights on any mesh).
-  * ``lsh``   — packed codes row-sharded; per-shard Hamming top-rerank via
-    ``backend.hamming_topk``.  Born rerank never replicates the vectors:
-    each shard scores the merged candidates it owns in f32 and the partial
-    score rows merge with ``lax.pmax``.
+  * ``lsh``   — packed codes, transposed (W, N), sharded by corpus row;
+    per-shard Hamming top-rerank via ``backend.hamming_topk``.  Born
+    rerank never replicates the vectors: each shard scores the merged
+    candidates it owns in f32 and the partial score rows merge with
+    ``lax.pmax``.
   * ``ivfflat`` — centroids replicate, so every shard selects the SAME
     global top-``nprobe`` probe set.  Born lists are partitioned by row
     *origin* shard — each shard keeps a (n_lists, cap_local) ELL of its
@@ -60,7 +61,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.distributed import collectives as coll
 from repro.distributed.compression import quantize_int8
@@ -69,7 +70,10 @@ from repro.distributed.sharding import RETRIEVAL_RULES, partition_axes
 from repro.kernels.topk_scoring import ops as topk_ops
 from repro.kernels.topk_scoring.ref import pad_topk as _pad_topk
 from repro.retrieval.backends import get_backend, rerank_candidates
+from repro.retrieval.ivfflat import assign_lists, fill_lists, list_ranks
 from repro.retrieval.lsh import encode
+
+_F32 = lax.Precision.HIGHEST      # XLA's TPU default rounds f32 to bf16
 
 
 def _resolve_axes(mesh: Mesh, axes: Optional[tuple]) -> tuple:
@@ -93,6 +97,11 @@ def _axis_count(mesh: Mesh, axes: tuple) -> int:
 def _row_spec(axes: tuple, ndim: int) -> P:
     lead = axes if len(axes) > 1 else axes[0]
     return P(lead, *([None] * (ndim - 1)))
+
+
+def _col_spec(axes: tuple) -> P:
+    """Column-sharded 2-D array: the LSH index's transposed (W, N) codes."""
+    return P(None, axes if len(axes) > 1 else axes[0])
 
 
 def _merge(s: jnp.ndarray, i: jnp.ndarray, axes: tuple, k: int):
@@ -138,7 +147,7 @@ def _rowwise_topk(backend, vecs: jnp.ndarray, queries: jnp.ndarray, *,
 
     fn = shard_map(shard_fn, mesh=mesh,
                    in_specs=(_row_spec(axes, 2), P(None, None)),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     return _pad_topk(*fn(vp, queries), k)
 
 
@@ -156,7 +165,7 @@ def _sharded_tfidf(engine, index, queries, *, k, mesh, axes):
 
 def _sharded_lsh(engine, index, queries, *, k, mesh, axes):
     backend = get_backend(engine.backend)
-    n = index.codes.shape[0]
+    n = index.vecs.shape[0]
     d = _axis_count(mesh, axes)
     rows = -(-n // d)
     rerank = min(max(engine.rerank, k), n) if engine.rerank > 0 else 0
@@ -165,15 +174,15 @@ def _sharded_lsh(engine, index, queries, *, k, mesh, axes):
     qc = encode(index.proj, queries)
     pad = rows * d - n
     if pad:
-        # a zero-padded code row would get a REAL Hamming distance and
+        # a zero-padded code column would get a REAL Hamming distance and
         # could evict a true candidate from the local top-k, so padded rows
         # get W+1 extra all-ones words (queries and real rows get zeros):
         # their distance grows by 32·(W+1) > 32·W ≥ any real distance,
         # strictly below every real row — exact integer arithmetic, and
         # real-row distances are untouched
-        w = index.codes.shape[1]
-        cp = jnp.pad(index.codes, ((0, pad), (0, w + 1)))
-        cp = cp.at[n:, w:].set(-1)
+        w = index.codes.shape[0]
+        cp = jnp.pad(index.codes, ((0, w + 1), (0, pad)))
+        cp = cp.at[w:, n:].set(-1)
         qc = jnp.pad(qc, ((0, 0), (0, w + 1)))
     else:
         cp = index.codes
@@ -187,8 +196,8 @@ def _sharded_lsh(engine, index, queries, *, k, mesh, axes):
                       jnp.where(ok, gid, -1), axes, target)
 
     fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(_row_spec(axes, 2), P(None, None)),
-                   out_specs=(P(), P()), check_rep=False)
+                   in_specs=(_col_spec(axes), P(None, None)),
+                   out_specs=(P(), P()), check_vma=False)
     neg, cand = fn(cp, qc)
     if rerank <= 0:
         # match search_lsh's historical no-rerank API: positive Hamming
@@ -214,7 +223,7 @@ def _sharded_ivfflat(engine, index, queries, *, k, mesh, axes):
 
     def shard_fn(v_l, i_l, m_l, cent, q):
         l0 = coll.flat_axis_index(axes) * ll
-        cscore = q @ cent.T                          # (Q, n_lists) global
+        cscore = jnp.dot(q, cent.T, precision=_F32)  # (Q, n_lists) global
         _, probe = lax.top_k(cscore, nprobe)         # same probes everywhere
         own = (probe >= l0) & (probe < l0 + ll)
         lp = jnp.clip(probe - l0, 0, ll - 1)
@@ -229,7 +238,7 @@ def _sharded_ivfflat(engine, index, queries, *, k, mesh, axes):
                    in_specs=(_row_spec(axes, 3), _row_spec(axes, 2),
                              _row_spec(axes, 2), P(None, None),
                              P(None, None)),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     return _pad_topk(*fn(vecs, ids, mask, index.centroids, queries), k)
 
 
@@ -287,7 +296,7 @@ class ShardedLSHIndex(NamedTuple):
     projection; ``aug`` marks the W+1 all-ones pad-sentinel words."""
 
     proj: Any        # f32[D, n_bits] replicated
-    codes: Any       # i32[rows·d, W(+W+1)] row-sharded
+    codes: Any       # i32[W(+W+1), rows·d] transposed, column-sharded
     vecs: Any        # f32[rows·d, D] row-sharded (rerank)
     n: int
     aug: bool
@@ -340,7 +349,7 @@ def _augment_rows(corpus: ShardedCorpus, row_vecs):
         return jnp.concatenate([v_l, sent[:, None]], axis=1)
 
     fn = shard_map(f, mesh=corpus.mesh, in_specs=(_row_spec(axes, 2),),
-                   out_specs=_row_spec(axes, 2), check_rep=False)
+                   out_specs=_row_spec(axes, 2), check_vma=False)
     return fn(row_vecs), True
 
 
@@ -355,7 +364,7 @@ def _quant_build(corpus: ShardedCorpus, row_vecs) -> ShardedQuantIndex:
 
     fn = shard_map(f, mesh=corpus.mesh, in_specs=(_row_spec(axes, 2),),
                    out_specs=(_row_spec(axes, 2), P(_lead_axes(axes))),
-                   check_rep=False)
+                   check_vma=False)
     codes, scales = fn(row_vecs)
     return ShardedQuantIndex(codes, scales, row_vecs, corpus.n)
 
@@ -387,7 +396,7 @@ def _build_born_tfidf(engine, corpus: ShardedCorpus, key):
         return v_l * w[None, :], w
 
     fn = shard_map(f, mesh=corpus.mesh, in_specs=(_row_spec(axes, 2),),
-                   out_specs=(_row_spec(axes, 2), P(None)), check_rep=False)
+                   out_specs=(_row_spec(axes, 2), P(None)), check_vma=False)
     weighted, w = fn(corpus.vecs)
     if engine.backend == "int8":
         quant = _quant_build(corpus, weighted)
@@ -404,21 +413,21 @@ def _build_born_lsh(engine, corpus: ShardedCorpus, key):
 
     def f(v_l, proj_):
         row0 = coll.flat_axis_index(axes) * rows
-        codes = encode(proj_, v_l)
+        codes = encode(proj_, v_l).T                 # (W, rows)
         if pad:
             # the legacy path's pad sentinel, applied at birth: pad rows
             # get W+1 extra all-ones words (real rows and queries zeros),
             # growing their Hamming distance past any real row's
-            w = codes.shape[1]
-            extra = jnp.where(_local_valid(row0, rows, n)[:, None],
+            w = codes.shape[0]
+            extra = jnp.where(_local_valid(row0, rows, n)[None, :],
                               jnp.int32(0), jnp.int32(-1))
             codes = jnp.concatenate(
-                [codes, jnp.broadcast_to(extra, (rows, w + 1))], axis=1)
+                [codes, jnp.broadcast_to(extra, (w + 1, rows))], axis=0)
         return codes
 
     fn = shard_map(f, mesh=corpus.mesh,
                    in_specs=(_row_spec(axes, 2), P(None, None)),
-                   out_specs=_row_spec(axes, 2), check_rep=False)
+                   out_specs=_col_spec(axes), check_vma=False)
     return ShardedLSHIndex(proj, fn(corpus.vecs, proj), corpus.vecs,
                            n, bool(pad))
 
@@ -430,15 +439,14 @@ def _build_born_ivfflat(engine, corpus: ShardedCorpus, key,
     them with one ``psum`` all-reduce per iteration — no device ever sees
     another shard's rows.  List fill is shard-local too: each shard packs
     its own rows into a (n_lists, cap_local) ELL keyed by the replicated
-    centroids."""
+    centroids, ``cap_local`` being the longest local list on any shard."""
     axes, d, rows, pad = _shard_geometry(corpus)
-    n, dim = corpus.n, corpus.dim
+    n = corpus.n
     n_lists = min(engine.n_lists, max(1, n // 8))
-    cap_l = int(engine.cap_factor * rows / n_lists) + 1
     # same init selection as ivfflat.kmeans (replicated): global row ids
     init_idx = jax.random.choice(key, n, (n_lists,), replace=False)
 
-    def f(v_l, init_g):
+    def lloyd(v_l, init_g):
         row0 = coll.flat_axis_index(axes) * rows
         valid = _local_valid(row0, rows, n)
 
@@ -449,11 +457,6 @@ def _build_born_ivfflat(engine, corpus: ShardedCorpus, key,
         cand = v_l[jnp.clip(lidx, 0, rows - 1)]
         cent0 = lax.psum(jnp.where(own[:, None], cand, 0.0), axes)
 
-        def assign_of(cent):
-            d2 = (jnp.sum(v_l ** 2, 1)[:, None] - 2.0 * v_l @ cent.T
-                  + jnp.sum(cent ** 2, 1)[None])
-            return jnp.argmin(d2, axis=1)
-
         # pad rows route to a dummy segment so they never pull a centroid;
         # the dummy is only materialised when pads exist (1-device parity)
         nseg = n_lists + 1 if pad else n_lists
@@ -461,7 +464,7 @@ def _build_born_ivfflat(engine, corpus: ShardedCorpus, key,
                else (lambda a: a))
 
         def step(cent, _):
-            a = seg(assign_of(cent))
+            a = seg(assign_lists(v_l, cent))
             sums = jax.ops.segment_sum(v_l, a,
                                        num_segments=nseg)[:n_lists]
             cnts = jax.ops.segment_sum(jnp.ones((rows, 1), v_l.dtype), a,
@@ -471,34 +474,30 @@ def _build_born_ivfflat(engine, corpus: ShardedCorpus, key,
             return new, None
 
         cent, _ = lax.scan(step, cent0, None, length=kmeans_iters)
+        a = seg(assign_lists(v_l, cent))
+        rank, counts = list_ranks(a, n_lists)
+        return cent, a, rank, counts[None]
 
-        # shard-local ELL list fill (build_ivfflat's fill over local rows)
-        a = seg(assign_of(cent))
-        order = jnp.argsort(a, stable=True)
-        sa = a[order]
-        starts = jnp.concatenate([jnp.ones((1,), bool),
-                                  sa[1:] != sa[:-1]])
-        iota = jnp.arange(rows, dtype=jnp.int32)
-        gstart = lax.associative_scan(jnp.maximum,
-                                      jnp.where(starts, iota, 0))
-        rank = iota - gstart
-        ok = rank < cap_l
-        row = jnp.where(ok, sa, n_lists)
-        col = jnp.where(ok, rank, 0)
-        lvecs = jnp.zeros((n_lists, cap_l, dim), v_l.dtype).at[
-            row, col].set(v_l[order], mode="drop")
-        lids = jnp.full((n_lists, cap_l), -1, jnp.int32).at[row, col].set(
-            (row0 + order).astype(jnp.int32), mode="drop")
-        lmask = jnp.zeros((n_lists, cap_l), bool).at[row, col].set(
-            jnp.ones((rows,), bool), mode="drop")
-        return cent, lvecs, lids, lmask
-
-    fn = shard_map(f, mesh=corpus.mesh,
+    fn = shard_map(lloyd, mesh=corpus.mesh,
                    in_specs=(_row_spec(axes, 2), P(None)),
-                   out_specs=(P(None, None), _row_spec(axes, 3),
-                              _row_spec(axes, 2), _row_spec(axes, 2)),
-                   check_rep=False)
-    cent, lvecs, lids, lmask = fn(corpus.vecs, init_idx)
+                   out_specs=(P(None, None), _row_spec(axes, 1),
+                              _row_spec(axes, 1), _row_spec(axes, 2)),
+                   check_vma=False)
+    cent, assign, rank, counts = fn(corpus.vecs, init_idx)
+    cap_l = int(jnp.max(counts))
+
+    def fill(v_l, a, r):
+        row0 = coll.flat_axis_index(axes) * rows
+        return fill_lists(v_l, row0 + jnp.arange(rows, dtype=jnp.int32), a,
+                          r, n_lists, cap_l)
+
+    fn = shard_map(fill, mesh=corpus.mesh,
+                   in_specs=(_row_spec(axes, 2), _row_spec(axes, 1),
+                             _row_spec(axes, 1)),
+                   out_specs=(_row_spec(axes, 3), _row_spec(axes, 2),
+                              _row_spec(axes, 2)),
+                   check_vma=False)
+    lvecs, lids, lmask = fn(corpus.vecs, assign, rank)
     return ShardedIVFIndex(cent, lvecs, lids, lmask, n)
 
 
@@ -539,7 +538,7 @@ def _distributed_rerank(v_l, q, cand, row0, rows: int, k: int, axes):
     lid = cand - row0
     own = (cand >= 0) & (lid >= 0) & (lid < rows)
     cv = v_l[jnp.clip(lid, 0, rows - 1)]
-    s = jnp.einsum("qd,qrd->qr", q, cv)
+    s = jnp.einsum("qd,qrd->qr", q, cv, precision=_F32)
     s = jnp.where(own, s, -jnp.inf)
     s = lax.pmax(s, axes)
     s = jnp.where(cand >= 0, s, -jnp.inf)
@@ -570,7 +569,7 @@ def _search_born_rows(backend, index_vecs, n: int, aug: bool, queries, *,
 
     fn = shard_map(f, mesh=mesh,
                    in_specs=(_row_spec(axes, 2), P(None, None)),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     return _pad_topk(*fn(index_vecs, queries), k)
 
 
@@ -602,7 +601,7 @@ def _search_born_quant(backend, index: ShardedQuantIndex, queries, *,
     fn = shard_map(f, mesh=mesh,
                    in_specs=(_row_spec(axes, 2), _row_spec(axes, 2),
                              P(None, None), P(None, None)),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     return fn(index.codes, index.vecs, q_codes, queries)
 
 
@@ -611,13 +610,13 @@ def _search_born_lsh(engine, index: ShardedLSHIndex, queries, *, k: int,
     backend = get_backend(engine.backend)
     n = index.n
     d = _axis_count(mesh, axes)
-    rows = index.codes.shape[0] // d
+    rows = index.codes.shape[1] // d
     rerank = min(max(engine.rerank, k), n) if engine.rerank > 0 else 0
     target = rerank if rerank > 0 else k
     t_l = min(target, rows)
     qc = encode(index.proj, queries)
     if index.aug:
-        qc = jnp.pad(qc, ((0, 0), (0, index.codes.shape[1] - qc.shape[1])))
+        qc = jnp.pad(qc, ((0, 0), (0, index.codes.shape[0] - qc.shape[1])))
 
     def f(c_l, v_l, qc_, q):
         row0 = coll.flat_axis_index(axes) * rows
@@ -631,9 +630,9 @@ def _search_born_lsh(engine, index: ShardedLSHIndex, queries, *, k: int,
         return _distributed_rerank(v_l, q, cand, row0, rows, k, axes)
 
     fn = shard_map(f, mesh=mesh,
-                   in_specs=(_row_spec(axes, 2), _row_spec(axes, 2),
+                   in_specs=(_col_spec(axes), _row_spec(axes, 2),
                              P(None, None), P(None, None)),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     s, ids = fn(index.codes, index.vecs, qc, queries)
     if rerank <= 0:
         # positive Hamming distance, matching search_lsh's no-rerank API
@@ -650,10 +649,13 @@ def _search_born_ivf(engine, index: ShardedIVFIndex, queries, *, k: int,
     k_l = min(k, nprobe * cap_l)
 
     def f(v_l, i_l, m_l, cent, q):
-        cscore = q @ cent.T                      # replicated centroids:
-        _, probe = lax.top_k(cscore, nprobe)     # same probes on all shards
-        v = v_l[probe]                           # (Q, nprobe, cap_l, dim)
-        cid = jnp.where(m_l[probe], i_l[probe], -1)
+        # replicated centroids: the same probes on all shards
+        cscore = jnp.dot(q, cent.T, precision=_F32)
+        _, probe = lax.top_k(cscore, nprobe)
+        # (list, slot) gather, as ivfflat.probe_candidates: no index copy
+        slot = (probe[..., None], jnp.arange(cap_l)[None, None])
+        v = v_l[slot]                            # (Q, nprobe, cap_l, dim)
+        cid = jnp.where(m_l[slot], i_l[slot], -1)
         qn = q.shape[0]
         s, gid = backend.gathered_topk(q, v.reshape(qn, -1, dim),
                                        cid.reshape(qn, -1), k=k_l)
@@ -663,7 +665,7 @@ def _search_born_ivf(engine, index: ShardedIVFIndex, queries, *, k: int,
                    in_specs=(_row_spec(axes, 3), _row_spec(axes, 2),
                              _row_spec(axes, 2), P(None, None),
                              P(None, None)),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     return _pad_topk(*fn(index.vecs, index.ids, index.mask,
                          index.centroids, queries), k)
 
@@ -690,7 +692,7 @@ def sharded_buffer_topk(buf_vecs, n_valid, queries, *, k: int, mesh: Mesh,
     def f(v_l, q, nv):
         row0 = coll.flat_axis_index(axes) * rows
         gid = row0 + jnp.arange(rows, dtype=jnp.int32)
-        s = (q @ v_l.T).astype(jnp.float32)
+        s = jnp.dot(q, v_l.T, precision=_F32).astype(jnp.float32)
         s = jnp.where((gid < nv)[None, :], s, -jnp.inf)
         top_s, pos = lax.top_k(s, k_l)
         top_i = jnp.where(jnp.isfinite(top_s), id_base + row0 + pos, -1)
@@ -698,7 +700,7 @@ def sharded_buffer_topk(buf_vecs, n_valid, queries, *, k: int, mesh: Mesh,
 
     fn = shard_map(f, mesh=mesh,
                    in_specs=(_row_spec(axes, 2), P(None, None), P()),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     return _pad_topk(*fn(buf_vecs, queries, jnp.int32(n_valid)), k)
 
 

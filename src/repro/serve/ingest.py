@@ -86,7 +86,8 @@ def _buffer_topk(queries, buf, n_valid, *, k: int, id_base: int):
     """Exact top-k over the (single-device) append buffer: rows at position
     ≥ ``n_valid`` (a dynamic scalar — appends never retrace) mask to −inf
     and can never displace a live row; ids offset by the frozen size."""
-    s = (queries @ buf.T).astype(jnp.float32)
+    s = jnp.dot(queries, buf.T, precision=lax.Precision.HIGHEST
+                ).astype(jnp.float32)
     pos = jnp.arange(buf.shape[0], dtype=jnp.int32)
     s = jnp.where((pos < n_valid)[None, :], s, -jnp.inf)
     top_s, top_p = lax.top_k(s, k)
@@ -180,6 +181,12 @@ class LiveIndex:
     def config(self) -> SearchConfig:
         with self._lock:
             return self._session.config
+
+    @property
+    def session(self) -> SearchSession:
+        """The frozen index's session (replaced at each compaction)."""
+        with self._lock:
+            return self._session
 
     # -- ingest ------------------------------------------------------------
 

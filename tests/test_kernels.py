@@ -38,8 +38,8 @@ def test_topk_scoring(q, n, d, k, dtype):
 
 @pytest.mark.parametrize("q,n,d,k,use_kernel", [
     (3, 50, 16, 7, True),     # q below block_q floor, n below block_n floor
-    (5, 40, 8, 60, True),     # k > 32 -> ref fallback, and k > n
-    (4, 8, 8, 33, True),      # ref fallback with k > n
+    (5, 40, 8, 60, True),     # k > 32 (kernel widens its tile), k > n
+    (4, 8, 8, 33, True),      # k > 32 with k > n
     (3, 5, 8, 9, True),       # kernel path with k > n
     (3, 5, 8, 9, False),      # forced ref with k > n
 ])
@@ -64,7 +64,7 @@ def test_topk_scoring_odd_shapes(q, n, d, k, use_kernel):
     (16, 256, 32, 3), (64, 1000, 64, 8), (7, 513, 16, 5),
     (3, 50, 16, 7),           # q and n below the block floors
     (3, 5, 8, 9),             # odd-small shape, k > n (pad-row hazard)
-    (5, 40, 8, 70),           # k > _MAX_KERNEL_K_INT8 -> ref fallback
+    (5, 40, 8, 70),           # k > 64: a wider partial tile
 ])
 def test_topk_scoring_int8(q, n, d, k):
     """int8 scoring kernel vs the int32-accumulate oracle.  Codes are drawn

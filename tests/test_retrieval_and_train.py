@@ -49,8 +49,7 @@ def test_ivfflat_recall(vectors):
 
 def test_ivfflat_full_probe_is_exact(vectors):
     corpus, queries, gt = vectors
-    idx = build_ivfflat(jax.random.PRNGKey(0), corpus, n_lists=8,
-                        cap_factor=8.0)
+    idx = build_ivfflat(jax.random.PRNGKey(0), corpus, n_lists=8)
     _, ids = search_ivfflat(idx, queries, k=5, nprobe=8)
     assert (np.sort(np.asarray(ids), 1) == np.sort(gt, 1)).all()
 

@@ -41,8 +41,14 @@ def test_dtype_str():
     assert tuning.dtype_str(np.dtype("int32")) == "int32"
 
 
+def _table(**meta):
+    """An empty table tuned, as far as its meta says, on this device."""
+    return tuning.TunedTable(meta={"device_kind": tuning.device_kind(),
+                                   **meta})
+
+
 def test_resolve_order_explicit_over_table_over_default():
-    table = tuning.TunedTable()
+    table = _table()
     table.add(tuning.TunedConfig("topk", "le1024", "float32",
                                  (("block_n", 256), ("block_q", 32))))
     tuning.set_table(table)
@@ -66,7 +72,7 @@ def test_resolve_unknown_param_raises():
 
 
 def test_set_table_none_forces_defaults():
-    table = tuning.TunedTable()
+    table = _table()
     table.add(tuning.TunedConfig("topk", "le1024", "float32",
                                  (("block_n", 128), ("block_q", 8))))
     tuning.set_table(table)
@@ -79,7 +85,7 @@ def test_set_table_none_forces_defaults():
 def test_env_escape_hatch_and_path(tmp_path):
     """REPRO_TUNED_KERNELS=off forces defaults; =<path> loads that table.
     Subprocess because the active table resolves once per process."""
-    table = tuning.TunedTable(meta={"origin": "test"})
+    table = _table(origin="test")
     table.add(tuning.TunedConfig("topk", "le1024", "float32",
                                  (("block_n", 512), ("block_q", 8))))
     path = tmp_path / "t.json"
@@ -162,7 +168,7 @@ def test_parity_under_absurd_tuned_blocks():
     """Correctness is block-independent: a tuned table pinning oversized
     blocks (clamped by the padded-n floor inside the kernels) must not
     change results."""
-    table = tuning.TunedTable()
+    table = _table()
     table.add(tuning.TunedConfig("topk", "le1024", "float32",
                                  (("block_n", 2048), ("block_q", 256))))
     tuning.set_table(table)
@@ -173,3 +179,47 @@ def test_parity_under_absurd_tuned_blocks():
     np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
                                rtol=1e-5, atol=1e-5)
     assert (np.asarray(i) == np.asarray(i_ref)).all()
+
+
+def test_table_from_another_device_kind_falls_back_to_defaults():
+    """Blocks tuned on one device kind never reach another: the checked-in
+    CPU table is ignored on a TPU, and a TPU table on the CPU."""
+    table = tuning.TunedTable(meta={"device_kind": "TPU v5 lite"})
+    table.add(tuning.TunedConfig("topk", "le1024", "float32",
+                                 (("block_n", 128), ("block_q", 8))))
+    tuning.set_table(table)
+    assert tuning.device_kind() != "TPU v5 lite"
+    assert tuning.lookup("topk", n=100, dtype="float32") == {}
+    assert tuning.resolve("topk", n=100, dtype="float32") == \
+        tuning.DEFAULTS["topk"]
+    # the checked-in table records the CPU it was tuned on
+    shipped = tuning.TunedTable.load(tuning.DEFAULT_TABLE_PATH)
+    assert shipped.meta["device_kind"] == "cpu"
+
+
+def test_results_table_in_cwd_is_not_read_implicitly(tmp_path):
+    """results/tuned_kernels.json under the working directory is what
+    ``--autotune`` writes; a fresh process loads the checked-in table
+    unless REPRO_TUNED_KERNELS names that file."""
+    table = _table()
+    table.add(tuning.TunedConfig("topk", "le1024", "float32",
+                                 (("block_n", 384), ("block_q", 8))))
+    table.save(str(tmp_path / tuning.RESULTS_TABLE_PATH))
+    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    script = ("from repro.kernels import tuning; "
+              "print(tuning.resolve('topk', n=100, dtype='float32'))")
+    env = dict(os.environ, PYTHONPATH=src + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    env.pop(tuning.ENV_VAR, None)
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         cwd=str(tmp_path), capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "384" not in out.stdout
+    env[tuning.ENV_VAR] = tuning.RESULTS_TABLE_PATH
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         cwd=str(tmp_path), capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "384" in out.stdout
