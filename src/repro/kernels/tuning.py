@@ -47,7 +47,6 @@ they were traced with until their jit cache is cleared.
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 import itertools
 import json
@@ -56,7 +55,6 @@ import time
 from typing import Any, Callable, Dict, Mapping, Optional, Sequence, Tuple
 
 from repro.obs import metrics as obs_metrics
-from repro.obs import trace as obs_trace
 
 ENV_VAR = "REPRO_TUNED_KERNELS"
 
@@ -480,30 +478,12 @@ def lookup(kernel: str, *, n: int, dtype: Any) -> Dict[str, int]:
     return table.lookup(kernel, size_bucket(n), dtype_str(dtype))
 
 
-# Observability (DESIGN.md §12): every resolve() bumps the tuned-table
-# hit/miss counters, and — while tracing is enabled — appends the concrete
-# resolution to a bounded log so the span wrapping the dispatch (e.g.
-# SearchSession's per-chunk span) can attach the block choice as attrs.
-_RESOLUTION_LOG: "collections.deque" = collections.deque(maxlen=512)
-_RESOLUTION_SEQ = itertools.count()
-
-
-def resolution_mark() -> int:
-    """Opaque mark; pass to :func:`resolutions_since` to read back every
-    block resolution that happened after it (tracing-enabled only)."""
-    return next(_RESOLUTION_SEQ)
-
-
-def resolutions_since(mark: int) -> list:
-    """Resolution records (kernel, bucket, dtype, params, tuned) logged
-    after ``mark``; empty when tracing is disabled or nothing dispatched."""
-    return [rec for seq, rec in _RESOLUTION_LOG if seq >= mark]
-
-
 def resolve(kernel: str, *, n: int, dtype: Any,
             **explicit: Optional[int]) -> Dict[str, int]:
     """Final block params for one dispatch: explicit kwarg > tuned table >
-    hard-coded default.  ``None`` explicit values mean 'not specified'."""
+    hard-coded default.  ``None`` explicit values mean 'not specified'.
+    Each call counts a ``tuning.resolve.hit`` or ``.miss`` (DESIGN.md
+    §12)."""
     params = dict(DEFAULTS[kernel])
     tuned = lookup(kernel, n=n, dtype=dtype)
     obs_metrics.REGISTRY.counter(
@@ -515,11 +495,6 @@ def resolve(kernel: str, *, n: int, dtype: Any,
                              f"{name!r}; known: {', '.join(params)}")
         if value is not None:
             params[name] = int(value)
-    if obs_trace.is_enabled():
-        _RESOLUTION_LOG.append((next(_RESOLUTION_SEQ), {
-            "kernel": kernel, "bucket": size_bucket(n),
-            "dtype": dtype_str(dtype), "params": dict(params),
-            "tuned": bool(tuned)}))
     return params
 
 

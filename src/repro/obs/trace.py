@@ -1,4 +1,4 @@
-"""Zero-dependency structured span tracer (DESIGN.md §12).
+"""Structured span tracer (DESIGN.md §12).
 
 One process-global tracer produces nested, attributed spans:
 
@@ -9,9 +9,22 @@ One process-global tracer produces nested, attributed spans:
         sp.set(n_entities=int(mask.sum()))
 
 Spans record wall time (``perf_counter``), a span/parent id pair (so a
-reader can reconstruct the nesting), and free-form JSON attributes, and are
-appended to a JSONL sink — one JSON object per line, written as each span
-closes.
+reader can reconstruct the nesting), and free-form JSON attributes.  Each
+record is kept in memory as its span closes and written to a JSONL sink,
+one JSON object per line, when the tracer is disabled (and so at exit)
+or when :data:`FLUSH_RECORDS` records are held, so a long-lived process
+stays bounded and no span pays for a write of its own.
+
+While enabled, the tracer also:
+
+  * mirrors every span into the JAX profiler: the span enters and exits a
+    ``jax.profiler.TraceAnnotation`` of its name, so under a profiler
+    trace it lands on the host plane of the ``.xplane.pb``, on the device
+    trace's clock (outside a profiler trace the annotation records
+    nothing);
+  * records each Python garbage collection as a ``python.gc`` span
+    (``generation``, ``collected``) through ``gc.callbacks``, nested in
+    whatever span the collection interrupted.
 
 The JAX-aware variant understands asynchronous dispatch: a plain timer
 around a jitted call measures dispatch, not execution.  ``jax_span``
@@ -20,8 +33,8 @@ cover; on exit the tracer calls ``jax.block_until_ready`` on them and
 records the blocked tail separately (``block_s``), so the span's duration
 is the true wall time of the computation:
 
-    with trace.jax_span("sampling.labels", engine="ell") as sp:
-        labels, changes = _labels_stage(...)
+    with trace.jax_span("sampling.labels.rounds", rounds=5) as sp:
+        labels, changes = _lp_rounds(...)
         sp.declare(labels, changes)
 
 Compile vs execute: the first call of a jitted function pays tracing +
@@ -33,14 +46,16 @@ execution (``launch/trace.py`` reports the per-stage compile share).
 
 Disabled is the default and is a strict no-op fast path: ``span()`` /
 ``jax_span()`` return one shared :data:`NOOP` singleton — no span object
-is allocated, nothing is retained, nothing is written (enforced by
-tests/test_obs.py).  Enable with the ``REPRO_TRACE=<path>`` environment
-variable (honoured at import) or programmatically / via the CLIs'
-``--trace <path>`` flag through :func:`enable`.
+is allocated, nothing is retained, nothing is written, no ``gc`` hook is
+installed and no annotation is entered (enforced by tests/test_obs.py).
+Enable with the ``REPRO_TRACE=<path>`` environment variable (honoured at
+import) or programmatically / via the CLIs' ``--trace <path>`` flag
+through :func:`enable`.
 """
 from __future__ import annotations
 
 import atexit
+import gc
 import itertools
 import json
 import os
@@ -50,8 +65,12 @@ from typing import Any, Dict, Optional
 
 ENV_VAR = "REPRO_TRACE"
 
-__all__ = ["ENV_VAR", "NOOP", "Span", "configure_from_env", "disable",
-           "enable", "enabled_path", "is_enabled", "jax_span", "span"]
+#: records held in memory before they are written to the sink
+FLUSH_RECORDS = 4096
+
+__all__ = ["ENV_VAR", "FLUSH_RECORDS", "NOOP", "Span", "configure_from_env",
+           "disable", "enable", "enabled_path", "is_enabled", "jax_span",
+           "span"]
 
 
 class _NoopSpan:
@@ -82,11 +101,15 @@ class _State:
         self.enabled = False
         self.path: Optional[str] = None
         self.sink = None                  # open file handle when enabled
-        self.lock = threading.Lock()
+        self.buffer: list = []            # records not yet written
+        # re-entrant: a collection inside a flush records its own span
+        self.lock = threading.RLock()
         self.ids = itertools.count(1)
-        self.local = threading.local()    # .stack: per-thread open span ids
+        # .stack: per-thread open span ids; .gc_span: collection under way
+        self.local = threading.local()
         self.seen_first: set = set()      # compile keys already traced
-        self.records_written = 0          # testability: sink write count
+        self.records_written = 0          # records that reached the sink
+        self.annotation = None            # jax.profiler.TraceAnnotation
 
 
 _STATE = _State()
@@ -103,18 +126,27 @@ def enable(path: str) -> None:
     """Open ``path`` as the process-global JSONL sink and start tracing.
     Parent directories are created; re-enabling to the same path appends."""
     disable()
+    from jax.profiler import TraceAnnotation
     dirname = os.path.dirname(path)
     if dirname:
         os.makedirs(dirname, exist_ok=True)
     _STATE.sink = open(path, "a", encoding="utf-8")
     _STATE.path = path
+    _STATE.annotation = TraceAnnotation
+    gc.callbacks.append(_on_gc)
     _STATE.enabled = True
 
 
 def disable() -> None:
-    """Stop tracing and close the sink (idempotent)."""
+    """Stop tracing, write the held records and close the sink
+    (idempotent)."""
     _STATE.enabled = False
-    sink, _STATE.sink, _STATE.path = _STATE.sink, None, None
+    _STATE.annotation = None
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+    with _STATE.lock:
+        _flush()
+        sink, _STATE.sink, _STATE.path = _STATE.sink, None, None
     if sink is not None:
         try:
             sink.close()
@@ -130,21 +162,44 @@ def enabled_path() -> Optional[str]:
     return _STATE.path
 
 
+def _flush() -> None:
+    """Write the held records to the sink; the caller holds the lock."""
+    records, _STATE.buffer = _STATE.buffer, []
+    sink = _STATE.sink
+    if sink is None or not records:
+        return
+    sink.write("".join(json.dumps(r, default=str) + "\n" for r in records))
+    sink.flush()
+    _STATE.records_written += len(records)
+
+
 def _write(record: Dict[str, Any]) -> None:
     with _STATE.lock:
-        sink = _STATE.sink
-        if sink is None:
+        if _STATE.sink is None:
             return
-        sink.write(json.dumps(record, default=str) + "\n")
-        sink.flush()
-        _STATE.records_written += 1
+        _STATE.buffer.append(record)
+        if len(_STATE.buffer) >= FLUSH_RECORDS:
+            _flush()
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks`` hook: one ``python.gc`` span per collection."""
+    if phase == "start":
+        sp = Span("python.gc", {"generation": info["generation"]})
+        _STATE.local.gc_span = sp.__enter__()
+        return
+    sp = getattr(_STATE.local, "gc_span", None)
+    if sp is not None:
+        _STATE.local.gc_span = None
+        sp.attrs["collected"] = info["collected"]
+        sp.__exit__(None, None, None)
 
 
 class Span:
     """One live span; created only while tracing is enabled."""
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "_jax",
-                 "_compile_key", "_outputs", "_t0", "_wall0")
+                 "_compile_key", "_outputs", "_annotation", "_t0", "_wall0")
 
     def __init__(self, name: str, attrs: Dict[str, Any], *,
                  jax_aware: bool = False,
@@ -156,12 +211,17 @@ class Span:
         self._outputs: list = []
         self.span_id = 0
         self.parent_id: Optional[int] = None
+        self._annotation = None
 
     def __enter__(self) -> "Span":
         stack = _stack()
         self.parent_id = stack[-1] if stack else None
         self.span_id = next(_STATE.ids)
         stack.append(self.span_id)
+        annotation = _STATE.annotation
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
         self._wall0 = time.time()
         self._t0 = time.perf_counter()
         return self
@@ -185,6 +245,8 @@ class Span:
             jax.block_until_ready(self._outputs)
             block_s = time.perf_counter() - t_block
         dur_s = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         stack = _stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()

@@ -37,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed.sharded_corpus import ShardedCorpus
-from repro.kernels import tuning
 from repro.obs import trace
 from repro.obs import memory as obs_memory
 from repro.retrieval.backends import get_backend
@@ -140,7 +139,6 @@ class SearchSession:
 
     def _search_chunk(self, queries: jnp.ndarray, k: int):
         cfg = self.config
-        mark = tuning.resolution_mark() if trace.is_enabled() else 0
         with trace.jax_span(
                 "search.chunk",
                 compile_key=(f"search.chunk/{cfg.engine}/{cfg.backend}/"
@@ -155,15 +153,8 @@ class SearchSession:
                 scores, ids = self.engine.search_scored(self.index, queries,
                                                         k=k)
             sp.declare(ids)
-            blocks = tuning.resolutions_since(mark)
-            if blocks:
-                # block choice per kernel dispatched inside this chunk
-                # (resolution happens at trace time, so steady-state calls
-                # that hit a cached jit trace carry no tuned_blocks attr)
-                sp.set(tuned_blocks=[
-                    {"kernel": b["kernel"], "params": b["params"],
-                     "tuned": b["tuned"]} for b in blocks])
-        return np.asarray(scores), np.asarray(ids)
+        with trace.span("search.readback", q=int(queries.shape[0])):
+            return np.asarray(scores), np.asarray(ids)
 
     def search_scored(self, queries, *, k: int):
         """(scores f32[Q, k], ids i32[Q, k]) for a query batch — −inf/−1
@@ -176,22 +167,28 @@ class SearchSession:
         q = np.asarray(queries)
         k_eff = max(1, min(k, self.corpus_size))
         chunk = self.config.query_chunk
-        parts = [self._search_chunk(jnp.asarray(q[i:i + chunk]), k_eff)
-                 for i in range(0, q.shape[0], chunk)]
-        if parts:
-            scores = np.concatenate([p[0] for p in parts], 0)
-            local = np.concatenate([p[1] for p in parts], 0)
-        else:
-            scores = np.full((0, k_eff), -np.inf, np.float32)
-            local = np.zeros((0, k_eff), np.int32)
-        if k_eff < k:
-            scores = np.pad(scores, ((0, 0), (0, k - k_eff)),
-                            constant_values=-np.inf)
-            local = np.pad(local, ((0, 0), (0, k - k_eff)),
-                           constant_values=-1)
-        if self.ids_map is not None:
-            local = np.where(local >= 0,
-                             self.ids_map[np.clip(local, 0, None)], -1)
+        with trace.span("search.scored", q=int(q.shape[0]),
+                        chunks=-(-q.shape[0] // chunk)):
+            parts = []
+            for i in range(0, q.shape[0], chunk):
+                with trace.jax_span("search.upload") as up:
+                    part = jnp.asarray(q[i:i + chunk])
+                    up.declare(part)
+                parts.append(self._search_chunk(part, k_eff))
+            if parts:
+                scores = np.concatenate([p[0] for p in parts], 0)
+                local = np.concatenate([p[1] for p in parts], 0)
+            else:
+                scores = np.full((0, k_eff), -np.inf, np.float32)
+                local = np.zeros((0, k_eff), np.int32)
+            if k_eff < k:
+                scores = np.pad(scores, ((0, 0), (0, k - k_eff)),
+                                constant_values=-np.inf)
+                local = np.pad(local, ((0, 0), (0, k - k_eff)),
+                               constant_values=-1)
+            if self.ids_map is not None:
+                local = np.where(local >= 0,
+                                 self.ids_map[np.clip(local, 0, None)], -1)
         return scores, local
 
     def search(self, queries, *, k: int) -> np.ndarray:

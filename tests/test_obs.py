@@ -1,8 +1,11 @@
 """Observability layer tests (DESIGN.md §12): the span tracer's disabled
-no-op fast path and JSONL round-trip, histogram percentiles against
+no-op fast path, JSONL round-trip, buffered sink, garbage-collection spans
+and profiler-trace mirror, histogram percentiles against
 hand-computed fixtures, serve latency percentiles end-to-end, PlanTrie
 counter parity with the legacy per-node sums, the drain step-bound guard,
 draw-cache hit/miss counters, and the launch/trace.py aggregator."""
+import gc
+import glob
 import json
 
 import jax
@@ -17,9 +20,13 @@ from repro.obs.timing import provenance, timeit
 
 @pytest.fixture(autouse=True)
 def _tracer_disabled():
-    """Every test starts and ends with the tracer off (process-global)."""
+    """Every test starts and ends with the tracer off (process-global).
+    Automatic collection is off meanwhile, so a test's sink holds
+    ``python.gc`` spans only where the test calls ``gc.collect()``."""
     trace.disable()
+    gc.disable()
     yield
+    gc.enable()
     trace.disable()
 
 
@@ -106,6 +113,94 @@ def test_span_records_error(tmp_path):
     trace.disable()
     (rec,) = trace_cli.load_spans(str(sink))
     assert rec["error"] == "ValueError: boom"
+
+
+def test_disabled_tracer_installs_no_hook_and_enters_no_annotation(
+        monkeypatch):
+    def refused(name):
+        raise AssertionError(f"annotation {name!r} entered while disabled")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refused)
+    with trace.span("outer"):
+        with trace.jax_span("inner") as sp:
+            sp.declare(jnp.ones(2))
+    gc.collect()
+    assert trace._on_gc not in gc.callbacks
+    assert trace._STATE.buffer == []
+
+
+# --------------------------------------------------------------------------
+# tracer: buffered sink, garbage-collection spans, profiler mirror
+# --------------------------------------------------------------------------
+
+def _lines(path):
+    return path.read_text().splitlines() if path.exists() else []
+
+
+def test_records_reach_the_sink_only_at_disable_and_at_the_bound(tmp_path):
+    sink = tmp_path / "spans.jsonl"
+    trace.enable(str(sink))
+    written = trace._STATE.records_written
+    for i in range(trace.FLUSH_RECORDS - 1):
+        with trace.span("s", i=i):
+            pass
+    assert _lines(sink) == []
+    assert trace._STATE.records_written == written
+    with trace.span("s", i=trace.FLUSH_RECORDS - 1):
+        pass
+    assert len(_lines(sink)) == trace.FLUSH_RECORDS
+    with trace.span("last"):
+        pass
+    assert len(_lines(sink)) == trace.FLUSH_RECORDS
+    trace.disable()
+    recs = trace_cli.load_spans(str(sink))
+    assert recs[-1]["name"] == "last"
+    assert [r["attrs"]["i"] for r in recs if r["name"] == "s"] == \
+        list(range(trace.FLUSH_RECORDS))
+    assert trace._STATE.records_written - written == len(recs)
+
+
+def test_gc_collection_is_a_span_and_its_hook_goes_at_disable(tmp_path):
+    sink = tmp_path / "spans.jsonl"
+    trace.enable(str(sink))
+    assert trace._on_gc in gc.callbacks
+    with trace.span("outer"):
+        gc.collect()
+    trace.disable()
+    assert trace._on_gc not in gc.callbacks
+    recs = trace_cli.load_spans(str(sink))
+    outer = next(r for r in recs if r["name"] == "outer")
+    full = [r for r in recs if r["name"] == "python.gc"
+            and r["attrs"]["generation"] == 2]
+    assert full, [r["name"] for r in recs]
+    assert full[-1]["parent"] == outer["id"]
+    assert full[-1]["attrs"]["collected"] >= 0
+    assert full[-1]["dur_s"] <= outer["dur_s"]
+    gc.collect()                         # nothing recorded once disabled
+    assert trace_cli.load_spans(str(sink)) == recs
+
+
+def test_spans_land_on_a_host_plane_of_the_profiler_trace(tmp_path):
+    from jax.profiler import ProfileData
+    trace.enable(str(tmp_path / "spans.jsonl"))
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        with trace.jax_span("mirror.outer") as sp:
+            with trace.span("mirror.inner"):
+                sp.declare(jnp.arange(8.0) * 2)
+    trace.disable()
+    (path,) = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                        recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("mirror."):
+                    found[ev.name] = (ev.start_ns, ev.duration_ns)
+    assert set(found) == {"mirror.outer", "mirror.inner"}
+    (o0, od), (i0, idur) = found["mirror.outer"], found["mirror.inner"]
+    assert o0 <= i0 and i0 + idur <= o0 + od     # the nesting is kept
 
 
 # --------------------------------------------------------------------------
@@ -361,9 +456,9 @@ def test_instrumented_stages_emit_spans(tmp_path):
     sink = tmp_path / "trace.jsonl"
     trace.enable(str(sink))
     vecs = jax.random.normal(jax.random.PRNGKey(0), (128, 16))
-    session = SearchSession(vecs, SearchConfig(engine="exact"),
+    session = SearchSession(vecs, SearchConfig(engine="exact", query_chunk=3),
                             key=jax.random.PRNGKey(0))
-    session.search(vecs[:8], k=3)
+    session.search(vecs[:8], k=3)                  # chunks of 3, 3 and 2
     q, e, s, _, _, ne = generate_qrels(num_queries=64, qrels_per_query=4,
                                        num_topics=8, seed=0)
     qrels = QRelTable(jnp.asarray(q), jnp.asarray(e), jnp.asarray(s),
@@ -372,6 +467,17 @@ def test_instrumented_stages_emit_spans(tmp_path):
                           spec=SamplerSpec(target_size=16.0, seed=0))
     samp.draw(seed=3)
     trace.disable()
-    names = {r["name"] for r in trace_cli.load_spans(str(sink))}
+    recs = trace_cli.load_spans(str(sink))
+    names = {r["name"] for r in recs}
     assert {"search.build", "search.chunk", "sampling.graph",
             "sampling.labels", "sampling.draw"} <= names
+
+    def named(name):
+        return [r for r in recs if r["name"] == name]
+
+    (scored,) = named("search.scored")
+    assert scored["attrs"] == {"q": 8, "chunks": 3}
+    steps = [r for r in recs if r["parent"] == scored["id"]]
+    assert [r["name"] for r in sorted(steps, key=lambda r: r["id"])] == \
+        ["search.upload", "search.chunk", "search.readback"] * 3
+    assert [r["attrs"]["q"] for r in named("search.readback")] == [3, 3, 2]
