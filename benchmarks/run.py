@@ -389,6 +389,7 @@ def bench_sampling():
 
     from repro.core import QRelTable, WindTunnelConfig, run_windtunnel
     from repro.core import engines as eng
+    from repro.core import label_prop as lp
     from repro.core import sampling_core as sc
     from repro.data.synthetic import generate_corpus
 
@@ -407,10 +408,13 @@ def bench_sampling():
     row("sampling_graph_build", us_graph, f"N={n_ent} Q={n_q}")
     edges, _ = sc._graph_stage(qrels, num_queries=n_q, num_entities=n_ent,
                                tau_quantile=0.5, fanout=16)
+    node_cap, edge_cap = lp.lp_caps(rows=qrels.entity_ids.shape[0],
+                                    fanout=16, num_nodes=n_ent,
+                                    edge_slots=edges.u.shape[0])
     for name in engines:
         us_lp = _timeit(lambda name=name: sc._labels_stage(
             edges, engine=name, num_entities=n_ent, max_degree=32,
-            rounds=5))
+            rounds=5, node_cap=node_cap, edge_cap=edge_cap))
         row(f"sampling_lp[{name}]", us_lp, f"N={n_ent} rounds=5 K=32")
 
     for name in engines:

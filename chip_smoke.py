@@ -136,9 +136,12 @@ def sampling_phase(num_queries: int, seed: int) -> str:
     labels = np.asarray(session.labels()[0])
     drawn = int(np.asarray(session.draw().entity_mask).sum())
     spec = session.spec
-    calls = custom_calls(sampling_core._labels_stage, session.graph()[0],
+    edges = session.graph()[0]
+    node_cap, edge_cap = session._lp_caps(edges)
+    calls = custom_calls(sampling_core._labels_stage, edges,
                          engine="pallas", num_entities=num_entities,
-                         max_degree=spec.max_degree, rounds=spec.lp_rounds)
+                         max_degree=spec.max_degree, rounds=spec.lp_rounds,
+                         node_cap=node_cap, edge_cap=edge_cap)
     del session
     require(calls > 0, "LP 'pallas' program holds no TPU custom call")
     ref = np.asarray(one_device_session(qrels, num_queries, num_entities,

@@ -106,7 +106,11 @@ def dedup_edges(edges: EdgeList) -> EdgeList:
     """Step 2: one edge per (u, v) pair, keeping max affinity.
 
     Output is aligned to run-starts of the (u, v)-sorted order; non-start
-    positions are masked out.
+    positions are masked out.  Invalid input rows sort last under
+    ``I32_MAX`` keys, so every valid output edge lies within the first
+    (number of valid input rows) slots: for :func:`affinity_pairs` over a
+    table of ``rows`` judgments, within rows * (fanout - 1) // 2 slots,
+    which label propagation slices to (``label_prop.lp_caps``).
     """
     n = edges.u.shape[0]
     uk = jnp.where(edges.valid, edges.u, su.I32_MAX)

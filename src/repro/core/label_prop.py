@@ -25,7 +25,7 @@ uniformly selectable execution strategies.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -123,6 +123,58 @@ def edges_to_ell(src, dst, w, valid, *, num_nodes: int, max_degree: int):
     nbr_t, wgt_t = edges_to_ell_t(src, dst, w, valid, num_nodes=num_nodes,
                                   max_degree=max_degree)
     return nbr_t.T, wgt_t.T
+
+
+# ---------------------------------------------------------------------------
+# Compaction: LP over the nodes that hold an edge
+# ---------------------------------------------------------------------------
+
+def lp_caps(*, rows: int, fanout: int, num_nodes: int,
+            edge_slots: int) -> Tuple[int, int]:
+    """Static bounds ``(node_cap, edge_cap)`` on the graph that Alg. 1
+    builds from a judgment table of ``rows`` rows: ``node_cap`` nodes that
+    can hold an edge, and ``edge_cap`` slots of the deduped edge list that
+    can hold a valid one.
+
+    A query whose top-``fanout`` keeps k' judgments emits C(k', 2) <=
+    k' (fanout - 1) / 2 pairs, so at most rows (fanout - 1) // 2 pairs are
+    valid, and ``dedup_edges`` puts them first.  A node with an edge is the
+    entity of a judgment and an endpoint of a valid edge.
+    """
+    edge_cap = min(edge_slots, max(1, rows * (fanout - 1) // 2))
+    return max(1, min(num_nodes, rows, 2 * edge_cap)), edge_cap
+
+
+def compact_nodes(src, dst, valid, *, num_nodes: int, size: int):
+    """Renumber the nodes that hold a valid edge of a symmetrized edge
+    list 0, 1, ... in id order (each edge is a source once in either
+    direction).
+
+    Returns (src, dst) remapped (0 on invalid rows), ``ids`` i32[size]
+    (compact node -> node id, ``num_nodes`` past the last) and the number
+    of such nodes.  The map is strictly increasing, so label order, the
+    min-label tie-break and every sort over (dst, ...) decide as on the
+    original ids.  ``size`` must be at least the number of such nodes
+    (:func:`lp_caps`).
+    """
+    mark = jnp.zeros((num_nodes,), bool).at[
+        jnp.where(valid, src, num_nodes)].set(True, mode="drop")
+    pos = jnp.cumsum(mark, dtype=jnp.int32) - 1
+    ids = jnp.full((size,), num_nodes, jnp.int32).at[
+        jnp.where(mark, pos, size)].set(
+            jnp.arange(num_nodes, dtype=jnp.int32), mode="drop")
+
+    def remap(x):
+        return jnp.where(valid, pos[jnp.where(valid, x, 0)], 0)
+
+    return remap(src), remap(dst), ids, pos[-1] + 1
+
+
+def expand_labels(labels, ids, *, num_nodes: int):
+    """Compact labels back to i32[num_nodes]: node ``ids[i]`` takes label
+    ``ids[labels[i]]``; every other node keeps its own id."""
+    return jnp.arange(num_nodes, dtype=jnp.int32).at[ids].set(
+        ids[labels], mode="drop")
 
 
 def ell_round_t(labels, nbr_t, wgt_t):

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 
 from repro.core import engines as eng
 from repro.core import graph_builder as gb
+from repro.core import label_prop as lp
 from repro.core import reconstructor as rc
 from repro.core import sampler as sm
 from repro.core.pipeline import WindTunnelConfig, WindTunnelResult
@@ -117,13 +118,23 @@ def _graph_stage(qrels, *, num_queries, num_entities, tau_quantile, fanout):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "engine", "num_entities", "max_degree", "rounds"))
-def _labels_stage(edges, *, engine, num_entities, max_degree, rounds):
-    src, dst, w, valid = gb.symmetrize(edges)
+    "engine", "num_entities", "max_degree", "rounds", "node_cap",
+    "edge_cap"))
+def _labels_stage(edges, *, engine, num_entities, max_degree, rounds,
+                  node_cap, edge_cap):
+    """LP over the first ``edge_cap`` edge slots, which hold every valid
+    edge, and the ``node_cap`` nodes that can hold one, renumbered in id
+    order (``lp.lp_caps``): the labels and changes of LP over every node
+    and slot, bit for bit, and the number of nodes that hold an edge."""
+    src, dst, w, valid = gb.symmetrize(
+        gb.EdgeList(*(a[:edge_cap] for a in edges)))
+    src, dst, ids, active = lp.compact_nodes(
+        src, dst, valid, num_nodes=num_entities, size=node_cap)
     res = eng.run_engine(eng.get_engine(engine), src, dst, w, valid,
-                         num_nodes=num_entities, max_degree=max_degree,
+                         num_nodes=node_cap, max_degree=max_degree,
                          rounds=rounds)
-    return res.labels, res.changes_per_round
+    return (lp.expand_labels(res.labels, ids, num_nodes=num_entities),
+            res.changes_per_round, active)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -291,19 +302,37 @@ class SamplerSession:
                 self._stage_sharded()
             else:
                 edges, _ = self.graph()
+                node_cap, edge_cap = self._lp_caps(edges)
                 with trace.jax_span("sampling.labels",
                                     engine=self.spec.engine,
                                     n=self.num_entities,
                                     rounds=self.spec.lp_rounds,
-                                    max_degree=self.spec.max_degree) as sp:
-                    self._labels = _labels_stage(
+                                    max_degree=self.spec.max_degree,
+                                    node_cap=node_cap,
+                                    edge_cap=edge_cap) as sp:
+                    labels, changes, active = _labels_stage(
                         edges, engine=self.spec.engine,
                         num_entities=self.num_entities,
                         max_degree=self.spec.max_degree,
-                        rounds=self.spec.lp_rounds)
+                        rounds=self.spec.lp_rounds,
+                        node_cap=node_cap, edge_cap=edge_cap)
+                    self._labels = (labels, changes)
                     sp.declare(self._labels)
+                # a host read: traced runs only, and never of a tracer
+                if trace.is_enabled() and not isinstance(active,
+                                                         jax.core.Tracer):
+                    REGISTRY.gauge("sampling.labels.active_nodes").set(
+                        active)
                 self._counts["labels"][0] += 1
         return self._labels
+
+    def _lp_caps(self, edges) -> Tuple[int, int]:
+        """Static ``(node_cap, edge_cap)`` of LP from the judgment table's
+        rows and the fanout (``lp.lp_caps``)."""
+        return lp.lp_caps(rows=self.qrels.entity_ids.shape[0],
+                          fanout=self.spec.fanout,
+                          num_nodes=self.num_entities,
+                          edge_slots=edges.u.shape[0])
 
     # -- draws --------------------------------------------------------------
 

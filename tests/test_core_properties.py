@@ -73,6 +73,37 @@ def test_affinity_graph_invariants(data):
         assert abs(got[k] - best[k]) < 1e-5
 
 
+@given(st.integers(0, 2**31), st.integers(2, 6), st.integers(1, 12),
+       st.booleans())
+def test_dedup_keeps_valid_edges_within_the_row_bound(seed, fanout, nq,
+                                                      full):
+    """``dedup_edges`` puts every valid edge in the first
+    rows (fanout - 1) // 2 slots, the prefix label propagation slices to
+    (``lp.lp_caps``); ``full`` gives each query exactly ``fanout``
+    distinct entities, where the bound is all but tight."""
+    rng = np.random.default_rng(seed)
+    if full:
+        q = np.repeat(np.arange(nq), fanout)
+        e = np.concatenate([rng.choice(3 * fanout, fanout, replace=False)
+                            for _ in range(nq)])
+    else:
+        n = int(rng.integers(1, 8 * nq + 1))
+        q = rng.integers(0, nq, n)
+        e = rng.integers(0, 4 * fanout, n)
+    s = rng.random(q.size).astype(np.float32)
+    qrels = gb.QRelTable(jnp.asarray(q, jnp.int32), jnp.asarray(e, jnp.int32),
+                         jnp.asarray(s), jnp.asarray(rng.random(q.size) < 0.9))
+    edges = gb.build_affinity_graph(qrels, num_queries=nq,
+                                    tau_quantile=0.0, fanout=fanout)
+    bound = q.size * (fanout - 1) // 2
+    valid = np.asarray(edges.valid)
+    assert not valid[bound:].any()
+    _, edge_cap = lp.lp_caps(rows=q.size, fanout=fanout,
+                             num_nodes=4 * fanout, edge_slots=valid.size)
+    assert not valid[edge_cap:].any()
+    assert valid.sum() <= edge_cap
+
+
 @given(st.integers(0, 2**31), st.integers(10, 60), st.integers(2, 6))
 def test_label_prop_engines_agree(seed, n_edges, max_deg):
     """Sort-based and ELL label propagation agree when no edges are dropped

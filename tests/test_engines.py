@@ -12,8 +12,9 @@ import numpy as np
 import pytest
 
 from repro.core import (QRelTable, WindTunnelConfig, available_engines,
-                        engines as eng, graph_builder as gb, run_windtunnel,
-                        run_windtunnel_sharded)
+                        engines as eng, graph_builder as gb,
+                        label_prop as lp, run_windtunnel,
+                        run_windtunnel_sharded, sampling_core as sc)
 from repro.data.synthetic import generate_corpus
 from repro.launch.mesh import make_host_mesh
 
@@ -112,3 +113,83 @@ def test_sharded_pipeline_matches_single_device(corpus, engine):
 def test_sharded_pipeline_rejects_sort_engine(corpus):
     with pytest.raises(ValueError, match="ELL-family"):
         _run(corpus, "sort", mesh=make_host_mesh())
+
+
+# ---------------------------------------------------------------------------
+# LP sized by the judgment table: compacted stage == full engine, bit for bit
+# ---------------------------------------------------------------------------
+
+SPREAD_N = 50_000      # entity ids far beyond the tables' rows
+
+
+def _table(q, e, s):
+    return QRelTable(jnp.asarray(np.asarray(q, np.int32)),
+                     jnp.asarray(np.asarray(e, np.int32)),
+                     jnp.asarray(np.asarray(s, np.float32)),
+                     jnp.ones(len(q), bool))
+
+
+def _judgments(n, seed=0):
+    """Fanout 4: 600 judgments over 200 queries and 300 entities scattered
+    over ``n`` ids, scores on a coarse grid (tied weights and label
+    scores).  Returns (qrels, num_queries, fanout, tau_quantile, n)."""
+    rng = np.random.default_rng(seed)
+    pool = rng.choice(n, 300, replace=False)
+    return (_table(rng.integers(0, 200, 600), pool[rng.integers(0, 300, 600)],
+                   rng.integers(1, 5, 600) / 4), 200, 4, 0.5, n)
+
+
+def _tight_judgments(seed=0):
+    """Fanout 2 with valid pairs filling rows (fanout - 1) // 2 slots: 300
+    of 400 queries judge two entities each, 250 of them in a ring and 50
+    as lone pairs on the highest ids (so the last valid slot is a pair
+    that nothing else connects), plus one lowest-score row on a query of
+    its own, which the tau filter drops."""
+    rng = np.random.default_rng(seed)
+    ids = np.sort(rng.choice(SPREAD_N, 350, replace=False))
+    ring, lone = rng.permutation(ids[:250]), ids[250:]
+    pairs = [(ring[i], ring[(i + 1) % 250]) for i in range(250)]
+    pairs += [(lone[2 * j], lone[2 * j + 1]) for j in range(50)]
+    q = np.r_[np.repeat(np.arange(300), 2), 399]
+    e = np.r_[np.ravel(pairs), ring[0]]
+    s = np.r_[rng.integers(1, 5, 600) / 4, 0.0]
+    return _table(q, e, s), 400, 2, 0.0, SPREAD_N
+
+
+CASES = {"spread": lambda: _judgments(SPREAD_N), "tight": _tight_judgments,
+         "dense": lambda: _judgments(300)}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+@pytest.mark.parametrize("engine", ["sort", "ell", "pallas"])
+def test_compacted_labels_stage_matches_full_engine(engine, case):
+    """The labels stage slices the edge list to the slots that can hold a
+    valid edge and renumbers the nodes that hold one, and still equals the
+    engine run over every node and edge slot: labels and per-round changes
+    bit for bit, and it counts the nodes with an edge.  Cases: entity ids
+    spanning far more than the table's rows (``spread``), the edge bound
+    tight (``tight``), and every entity able to hold an edge, so node_cap
+    is N (``dense``)."""
+    qrels, nq, fanout, tau, n = CASES[case]()
+    edges = gb.build_affinity_graph(qrels, num_queries=nq, fanout=fanout,
+                                    tau_quantile=tau)
+    node_cap, edge_cap = lp.lp_caps(rows=qrels.entity_ids.shape[0],
+                                    fanout=fanout, num_nodes=n,
+                                    edge_slots=edges.u.shape[0])
+    assert (node_cap == n) == (case == "dense")
+    assert edge_cap < edges.u.shape[0]
+    valid = np.asarray(edges.valid)
+    assert not valid[edge_cap:].any()
+    if case == "tight":
+        assert valid.sum() == edge_cap and valid[edge_cap - 1]
+    labels, changes, active = sc._labels_stage(
+        edges, engine=engine, num_entities=n, max_degree=8, rounds=5,
+        node_cap=node_cap, edge_cap=edge_cap)
+    ref = eng.run_engine(eng.get_engine(engine), *gb.symmetrize(edges),
+                         num_nodes=n, max_degree=8, rounds=5)
+    assert np.array_equal(np.asarray(labels), np.asarray(ref.labels))
+    assert np.array_equal(np.asarray(changes),
+                          np.asarray(ref.changes_per_round))
+    assert np.asarray(changes).sum() > 0
+    degrees = np.asarray(gb.node_degrees(edges, n))
+    assert int(active) == (degrees > 0).sum()

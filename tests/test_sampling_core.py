@@ -216,6 +216,58 @@ def test_sharded_session_bit_equal_on_host_mesh(corpus, qrels, engine):
 # strategies: fraction targets, universes, degree stratification
 # ---------------------------------------------------------------------------
 
+def test_labels_span_and_active_nodes_gauge(tmp_path):
+    """The ``sampling.labels`` span carries LP's static caps, and in a
+    traced run the ``sampling.labels.active_nodes`` gauge counts the nodes
+    with an edge; an untraced session reads nothing back for it, and a
+    session traced under ``jax.jit`` sets no gauge from a tracer."""
+    from repro.core import label_prop as lp
+    from repro.launch import trace as trace_cli
+    from repro.obs import REGISTRY, trace
+    n, nq, rows = 20_000, 64, 256
+    rng = np.random.default_rng(0)
+    pool = rng.choice(n, 160, replace=False)
+    qrels = QRelTable(jnp.asarray(rng.integers(0, nq, rows), jnp.int32),
+                      jnp.asarray(pool[rng.integers(0, 160, rows)], jnp.int32),
+                      jnp.asarray(rng.random(rows), jnp.float32),
+                      jnp.ones(rows, bool))
+    spec = SamplerSpec(engine="ell", fanout=4, lp_rounds=3, max_degree=8,
+                       target_size=0.3)
+    gauge = REGISTRY.gauge("sampling.labels.active_nodes")
+    gauge.set(-1)
+    untraced = SamplerSession(qrels, num_queries=nq, num_entities=n,
+                              spec=spec).labels()[0]
+    assert gauge.value == -1
+    sink = tmp_path / "trace.jsonl"
+    cfg = spec.to_config()
+    trace.enable(str(sink))
+    try:
+        session = SamplerSession(qrels, num_queries=nq, num_entities=n,
+                                 spec=spec)
+        session.labels()
+        active = gauge.value
+        gauge.set(-1)
+        jitted = jax.jit(lambda q: run_windtunnel(
+            q, num_queries=nq, num_entities=n, config=cfg).labels)(qrels)
+        assert gauge.value == -1
+    finally:
+        trace.disable()
+    edges, degrees = session.graph()
+    node_cap, edge_cap = lp.lp_caps(rows=rows, fanout=4, num_nodes=n,
+                                    edge_slots=edges.u.shape[0])
+    assert node_cap < n
+    recs = [r for r in trace_cli.load_spans(str(sink))
+            if r["name"] == "sampling.labels"]
+    assert len(recs) == 2
+    for rec in recs:
+        assert (rec["attrs"]["node_cap"], rec["attrs"]["edge_cap"]) == (
+            node_cap, edge_cap)
+    assert active == int((np.asarray(degrees) > 0).sum()) > 0
+    for labels in (untraced, jitted):
+        assert np.array_equal(np.asarray(labels),
+                              np.asarray(session.labels()[0]))
+
+
 def test_fraction_target_matches_absolute_target(corpus, qrels):
     session = _session(corpus, qrels)
     deg = np.asarray(session.graph()[1])
