@@ -12,5 +12,7 @@ gives it:
 * ``metrics/<metric>.py`` — the reader of one per-layer metric, or
   ``metrics/<stem>.py`` for every ``<stem>.*`` metric that shares one;
 * ``work/<kernel>.py`` — a kernel's operations and bytes from shapes;
-* ``peaks.json`` — the chips' published peaks, by ``device_kind``.
+* ``peaks.json`` — the chips' published peaks, by ``device_kind``;
+* ``tests/sizes/{configs,traffic}/<name>.json`` — the values that put a
+  configuration or a traffic mix at a size the CPU tests hold.
 """

@@ -350,13 +350,15 @@ class Reference:
         return out
 
 
-def control(config: Dict, traffic: Dict, seed: int) -> Dict[str, float]:
+def control(config: Dict, traffic: Dict, seed: int,
+            devices) -> Dict[str, float]:
     """The numbers compared when the reference computed in bfloat16 is put
     in the program's place: judgment scores rounded to bfloat16 (so the
     tau filter and the edge weights are bfloat16 values), propagation
     weights and sums in bfloat16; its edges, degrees, labels and its draw
-    from those labels, against the float32 reference."""
-    del traffic
+    from those labels, against the float32 reference.  It runs on the
+    default device, as the cell's reference does."""
+    del traffic, devices
     target = config["sample_fraction"]
     inputs = make_inputs(config, seed)
     exact = Reference(config, inputs)

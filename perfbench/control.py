@@ -19,19 +19,22 @@ import argparse
 import json
 import os
 import sys
-from typing import Dict
+from typing import Any, Dict, Sequence
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def control(root: str, workload: str, seed: int) -> Dict[str, object]:
+def control(root: str, workload: str, seed: int,
+            devices: Sequence[Any]) -> Dict[str, object]:
+    """The cell's numbers compared, read from its configuration's control
+    on ``devices``, and which of them fail their limits."""
     from perfbench.harness import registry as reg
     r = reg.Registry(root)
     spec = reg.find_cell(reg.load_benchmark(), workload)
     cell = {"config": r.config(spec["config"]),
             "traffic": r.traffic(spec["traffic"])}
     numbers = r.reference(spec["config"]).control(
-        cell["config"], cell["traffic"], seed)
+        cell["config"], cell["traffic"], seed, devices)
     limits = dict(cell["config"]["limits"])
     failed = {n: v for n, v in numbers.items()
               if n in limits and not v <= limits[n]}
@@ -48,15 +51,16 @@ def main(argv=None) -> int:
     from perfbench.harness import registry as reg
     from perfbench.harness.runner import NoChip, accelerator, \
         use_compile_cache
+    chips = int(reg.find_cell(reg.load_benchmark(), args.workload)["chips"])
     use_compile_cache()
     try:
-        accelerator(1)
+        devices = accelerator(chips)
     except NoChip as e:
         print(f"control: {e}", file=sys.stderr)
         return 3
     for seed in (int(s) for s in args.seeds.split(",")):
-        print(json.dumps(control(reg.BENCH_DIR, args.workload, seed)),
-              flush=True)
+        print(json.dumps(control(reg.BENCH_DIR, args.workload, seed,
+                                 devices)), flush=True)
     return 0
 
 

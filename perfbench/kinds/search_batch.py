@@ -1,6 +1,7 @@
 """Traffic kind ``search_batch``: the experiment grid's evaluation pass.
 
-Set-up builds one ``SearchSession`` over the configuration's corpus.  Each
+Set-up builds one ``SearchSession`` over the configuration's corpus, made
+on the cell's devices (over several, row-sharded from birth).  Each
 pass of the window sends the traffic's whole query set through
 ``SearchSession.search_scored`` in chunks of ``query_chunk`` at top-``k``,
 pass after pass (a closed loop of one client).  ``search_qps`` is the
@@ -29,9 +30,11 @@ class Driver:
         c, t = self.cell.config, self.cell.traffic
         ref = self.cell.ref
         self.queries = ref.make_queries(c, self.cell.seed, t["queries"])
-        corpus = ref.make_corpus(c, self.cell.seed)
+        devices = self.cell.devices
+        corpus = program.search_corpus(
+            ref.make_corpus(c, self.cell.seed, devices), c, devices)
         self.session = SearchSession(corpus, program.search_config(
-            c, t["query_chunk"]))
+            c, t["query_chunk"], devices))
         del corpus
         self._pass()                 # warms every chunk shape of a pass
 
@@ -67,8 +70,8 @@ class Driver:
         p, q = np.divmod(pick, t["queries"])
         scores = np.stack([self.passes[a][0][b] for a, b in zip(p, q)])
         ids = np.stack([self.passes[a][1][b] for a, b in zip(p, q)])
-        corpus = np.asarray(self.cell.ref.make_corpus(c, self.cell.seed))
-        numbers = self.cell.ref.compare(corpus, self.queries[q], ids, scores,
-                                        t["k"], c["tie_rtol"])
+        ref = self.cell.ref
+        corpus = ref.make_corpus(c, self.cell.seed, self.cell.devices)
+        numbers = ref.compare(c, corpus, self.queries[q], ids, scores, t["k"])
         return [Check(name, numbers[name], limit)
                 for name, limit in c["limits"].items()]

@@ -31,9 +31,10 @@ class Driver:
         c, t = self.cell.config, self.cell.traffic
         ref = self.cell.ref
         self.pool = ref.make_queries(c, self.cell.seed, t["pool"])
-        corpus = [ref.make_corpus(c, self.cell.seed)]
+        corpus = [ref.make_corpus(c, self.cell.seed, self.cell.devices)]
         # the tenant is built once; then the server holds the only copy
-        self.server = program.search_server(lambda tenant: corpus.pop(), c, t)
+        self.server = program.search_server(lambda tenant: corpus.pop(), c, t,
+                                            self.cell.devices)
         for bucket in self.server.scheduler.config.bucket_set():
             pending = [self._submit(i) for i in range(bucket)]
             while self.server.tick():
@@ -88,9 +89,9 @@ class Driver:
         scores = np.stack([g[0] for g in got])
         ids = np.stack([g[1] for g in got])
         queries = self.pool[res.item[pick]]
-        corpus = np.asarray(self.cell.ref.make_corpus(c, self.cell.seed))
-        numbers = self.cell.ref.compare(corpus, queries, ids, scores,
-                                        t["k"], c["tie_rtol"])
+        ref = self.cell.ref
+        corpus = ref.make_corpus(c, self.cell.seed, self.cell.devices)
+        numbers = ref.compare(c, corpus, queries, ids, scores, t["k"])
         numbers["unanswered"] = float(res.failed)
         return [Check(name, numbers[name], limit)
                 for name, limit in {**c["limits"],

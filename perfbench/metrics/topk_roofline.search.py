@@ -2,7 +2,9 @@
 take at the chip's published peaks (``work/topk_scores.py``, each chunk of
 each pass against the whole corpus), over the device's busy time in the
 window, in percent.  The pad copy and the merge of partial lists are in
-the busy time and not in the work."""
+the busy time and not in the work.  Over several chips, each scans its
+share of the rows and the busy time is the chips' mean, so the work is
+one chip's share."""
 from perfbench.harness.roofline import least_time_s
 from perfbench.work import topk_scores
 
@@ -13,6 +15,7 @@ def read(r):
     if not chunks or busy <= 0:
         return None
     c = r.cell.config
-    per_pass = sum(least_time_s(*topk_scores.work(q, c["rows"], c["dim"]),
+    rows = c["rows"] / r.cell.chips
+    per_pass = sum(least_time_s(*topk_scores.work(q, rows, c["dim"]),
                                 r.peaks, topk_scores.DTYPE) for q in chunks)
     return 100.0 * r.window.data["passes"] * per_pass / busy

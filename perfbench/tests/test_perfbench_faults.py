@@ -1,7 +1,8 @@
 """With the timed path broken underneath, a run through the harness comes
-out not correct: once for each fault a one-chip cell can have (a step that
-returns its state unchanged, half of the batch left out, an answer altered
-where it is produced).  The exchange between chips has no one-chip cell."""
+out not correct: once for each fault a cell can have (a step that returns
+its state unchanged, half of the batch left out, an answer altered where it
+is produced, and on the four-device search cell the exchange between chips
+left out)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -86,3 +87,24 @@ def _break_search(monkeypatch, how):
 def test_search_answers_broken(small_root, monkeypatch, cell, how):
     _break_search(monkeypatch, how)
     _failed(run_small(small_root, cell), "topk_miss", "score_gap")
+
+
+def drop_one_shard():
+    """Planted in the child run: the merge of the chips' partial top-k
+    lists leaves the last chip's list out."""
+    from jax import lax
+    from repro.retrieval import sharded
+
+    def merge(s, i, axes, k):
+        w = s.shape[1]
+        s = lax.all_gather(s, axes, axis=1, tiled=True)[:, :-w]
+        i = lax.all_gather(i, axes, axis=1, tiled=True)[:, :-w]
+        top_s, pos = lax.top_k(s, min(k, s.shape[1]))
+        return top_s, jnp.take_along_axis(i, pos, axis=1)
+
+    sharded._merge = merge
+
+
+def test_exchange_between_chips_left_out(small_root):
+    _failed(run_small(small_root, "search.dense768.mesh4",
+                      plant=f"{__name__}:drop_one_shard"), "topk_miss")

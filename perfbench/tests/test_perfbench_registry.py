@@ -2,13 +2,15 @@
 a cell's files by name."""
 import json
 import os
+import shutil
 import subprocess
 import sys
 
 import pytest
 
 from perfbench.harness import registry as reg
-from perfbench.tests.conftest import CHECKOUT, bench
+from perfbench.tests.conftest import (CHECKOUT, SIZES, bench,
+                                      make_small_root, run_small)
 
 BENCH = reg.load_benchmark()
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
@@ -81,16 +83,20 @@ def test_every_named_file_is_there():
             hasattr(r.reference(cfg["name"]), "make_inputs")
         assert callable(r.reference(cfg["name"]).control)
     for cell in full["workloads"]:
-        assert cell["chips"] == 1
+        assert cell["chips"] in (1, 4)
         kind = r.kind(r.traffic(cell["traffic"])["kind"])
         assert hasattr(kind, "Driver")
+    fours = sum(cell["chips"] == 4 for cell in BENCH["workloads"])
+    assert fours <= max(1, len(BENCH["workloads"]) // 2)
     for m in full["per_layer"]:
         assert callable(r.metric(m["name"]).read)
 
 
 def test_new_files_are_found_by_name_with_no_edit(tmp_path):
     """A configuration, a traffic mix, a driver and a per-layer reader
-    dropped into a directory are found by the names an entry gives."""
+    dropped into a directory are found by the names an entry gives; a
+    configuration of four chips, added as files and a size file, runs
+    through the harness with no edit to a file that is there."""
     root = tmp_path / "bench"
     for sub in ("configs", "traffic", "kinds", "metrics"):
         (root / sub).mkdir(parents=True)
@@ -124,6 +130,37 @@ def test_new_files_are_found_by_name_with_no_edit(tmp_path):
         r.traffic("absent")
     with pytest.raises(reg.RegistryError):
         reg.find_cell(bench, "absent")
+
+    bench_dir, sizes = tmp_path / "perfbench", tmp_path / "sizes"
+    shutil.copytree(reg.BENCH_DIR, bench_dir, ignore=shutil.ignore_patterns(
+        "__pycache__"))
+    shutil.copytree(SIZES, sizes)
+    cfg = json.loads((bench_dir / "configs" / "msmarco-dense768-shard.json")
+                     .read_text())
+    cfg.update(name="toy-dense96", rows=100003, dim=96)
+    (bench_dir / "configs" / "toy-dense96.json").write_text(json.dumps(cfg))
+    # its maker leaves the rows on one device: the harness streams them in
+    (bench_dir / "configs" / "toy-dense96_ref.py").write_text(
+        (bench_dir / "configs" / "msmarco-dense768-shard_ref.py").read_text()
+        + "\n\ndef make_corpus(config, seed, devices):\n"
+          "    with jax.default_device(devices[0]):\n"
+          "        return _normal(_key(seed, 0), rows=config['rows'],\n"
+          "                       dim=config['dim'])\n")
+    (sizes / "configs" / "toy-dense96.json").write_text(
+        '{"rows": 2001, "checked_answers": 32}')
+    toy = {"end_to_end": [m for m in BENCH["end_to_end"]
+                          if m["name"] in ("setup_s", "search_qps")],
+           "per_layer": [],
+           "workloads": [{"name": "toy.dense96.x4", "config": "toy-dense96",
+                          "traffic": "batch", "chips": 4}]}
+    toy["end_to_end"][1] = {**toy["end_to_end"][1],
+                            "workloads": ["toy.dense96.x4"]}
+    root = make_small_root(str(tmp_path / "small"), str(bench_dir),
+                           str(sizes))
+    line = run_small(root, "toy.dense96.x4", benchmark=toy, sizes=str(sizes))
+    assert line["correct"] is True, line["checks"]
+    assert line["device"]["count"] == 4
+    assert set(line["metrics"]) == {"setup_s", "search_qps"}
 
 
 def test_a_run_without_a_tpu_exits_non_zero_and_prints_no_result():
