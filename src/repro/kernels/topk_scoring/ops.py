@@ -1,12 +1,14 @@
-"""Dispatch wrappers for topk_scoring: pad to block multiples and select
-interpret mode off-TPU.  The kernels take any k (their partial tiles widen
-in steps of 128 lanes); the jnp oracle runs only when a caller asks for it
-with ``use_kernel=False``.
+"""Dispatch wrappers for topk_scoring: pad the queries to block multiples
+and select interpret mode off-TPU.  The kernels take any k (their partial
+tiles widen in steps of 128 lanes); the jnp oracle runs only when a caller
+asks for it with ``use_kernel=False``.
 
 Shape contract (the engine path depends on it): any Q/N/C/k combination is
-accepted — k is clamped to the candidate count, inputs are padded to block
-multiples, and missing results come back as score −inf / id −1, so callers
-never see a ``lax.top_k`` shape error from an undersized corpus.
+accepted — k is clamped to the candidate count, queries are padded to block
+multiples (the corpus never is: the kernel masks a ragged last block and
+any row past the caller's ``n_valid``), and missing results come back as
+score −inf / id −1, so callers never see a ``lax.top_k`` shape error from
+an undersized corpus.
 
 Block sizes resolve through the autotuner table (kernels/tuning.py,
 DESIGN.md §11): explicit kwarg > tuned entry for the corpus-size bucket >
@@ -27,7 +29,6 @@ from repro.kernels import tuning
 from repro.kernels.topk_scoring import ref
 from repro.kernels.topk_scoring.ref import pad_topk as _pad_topk
 from repro.kernels.topk_scoring.topk_scoring import (gathered_topk_pallas,
-                                                     topk_scores_int8_pallas,
                                                      topk_scores_pallas)
 
 
@@ -36,85 +37,58 @@ def _ceil8(n: int) -> int:
 
 
 def topk_scores(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int,
-                block_q: int = None, block_n: int = None,
+                n_valid=None, block_q: int = None, block_n: int = None,
                 use_kernel: bool = True):
-    """Top-k inner-product search: (Q, D) x (N, D) -> (Q, k) scores/ids."""
-    blocks = tuning.resolve("topk", n=corpus.shape[0], dtype=queries.dtype,
-                            block_q=block_q, block_n=block_n)
-    return _topk_scores(queries, corpus, k=k, use_kernel=use_kernel,
-                        **blocks)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "block_q", "block_n",
-                                             "use_kernel"))
-def _topk_scores(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int,
-                 block_q: int, block_n: int, use_kernel: bool):
+    """Top-k inner-product search: (Q, D) x (N, D) -> (Q, k) scores/ids.
+    Rows at or past ``n_valid`` (an int or a traced int32 scalar; all N
+    rows by default) are not candidates."""
     n = corpus.shape[0]
-    k_eff = min(k, n)
-    if not use_kernel:
-        return _pad_topk(*ref.topk_scores_ref(queries, corpus, k=k_eff), k)
-    qn, d = queries.shape
-    bq = min(block_q, max(8, qn))
-    bn = min(block_n, _ceil8(n))
-    pad_q = (-qn) % bq
-    pad_n = (-n) % bn
-    # sentinel coordinate: query coord 1, real candidates 0, padding -BIG —
-    # padded rows then score -BIG and can never displace real candidates
-    qp = jnp.pad(queries.astype(jnp.float32), ((0, pad_q), (0, 1)),
-                 constant_values=1.0)
-    qp = qp.at[:, d].set(1.0)
-    cp = jnp.pad(corpus.astype(jnp.float32), ((0, pad_n), (0, 1)))
-    if pad_n:
-        cp = cp.at[n:, d].set(-1e30)
-    s, i = topk_scores_pallas(qp, cp, k=k_eff, block_q=bq, block_n=bn,
-                              interpret=tuning.interpret_mode())
-    if pad_n:
-        bad = i >= n
-        s = jnp.where(bad, -jnp.inf, s)
-        i = jnp.where(bad, -1, i)
-    return _pad_topk(s[:qn], i[:qn], k)
+    blocks = tuning.resolve("topk", n=n, dtype=queries.dtype,
+                            block_q=block_q, block_n=block_n)
+    return _topk_scores(queries, corpus, n if n_valid is None else n_valid,
+                        k=k, use_kernel=use_kernel, **blocks)
 
 
 def topk_scores_int8(q_codes: jnp.ndarray, c_codes: jnp.ndarray, *, k: int,
-                     block_q: int = None, block_n: int = None,
+                     n_valid=None, block_q: int = None, block_n: int = None,
                      use_kernel: bool = True):
     """Quantized top-k scan: int8 codes (Q, D) x (N, D) -> (Q, k) int-dot
-    scores (as f32) and ids.  Ranking is scale-invariant — dequantizing by
-    the global query/corpus scales multiplies every score by the same
-    positive constant — so callers rank on the raw dot and rerank the
-    winners in float (retrieval/backends.py Int8Backend)."""
-    blocks = tuning.resolve("topk", n=c_codes.shape[0], dtype="int8",
-                            block_q=block_q, block_n=block_n)
-    return _topk_scores_int8(q_codes, c_codes, k=k, use_kernel=use_kernel,
-                             **blocks)
+    scores (as f32) and ids, rows at or past ``n_valid`` left out.
+    Ranking is scale-invariant — dequantizing by the global query/corpus
+    scales multiplies every score by the same positive constant — so
+    callers rank on the raw dot and rerank the winners in float
+    (retrieval/backends.py Int8Backend)."""
+    n = c_codes.shape[0]
+    blocks = tuning.resolve("topk", n=n, dtype="int8", block_q=block_q,
+                            block_n=block_n)
+    return _topk_scores(q_codes, c_codes, n if n_valid is None else n_valid,
+                        k=k, use_kernel=use_kernel, **blocks)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_q", "block_n",
                                              "use_kernel"))
-def _topk_scores_int8(q_codes: jnp.ndarray, c_codes: jnp.ndarray, *, k: int,
-                      block_q: int, block_n: int, use_kernel: bool):
-    n = c_codes.shape[0]
+def _topk_scores(queries: jnp.ndarray, corpus: jnp.ndarray, n_valid, *,
+                 k: int, block_q: int, block_n: int, use_kernel: bool):
+    """f32 rows, or int8 codes on both sides.  Only the queries are padded
+    (to a block multiple): the kernel bounds the corpus's rows itself, so
+    the corpus is read where it lies and never copied."""
+    n = corpus.shape[0]
     k_eff = min(k, n)
     if not use_kernel:
-        return _pad_topk(
-            *ref.topk_scores_int8_ref(q_codes, c_codes, k=k_eff), k)
-    qn = q_codes.shape[0]
-    bq = min(block_q, max(8, qn))
-    bn = min(block_n, _ceil8(n))
-    pad_q = (-qn) % bq
-    pad_n = (-n) % bn
-    # zero-padding only: padded rows are masked by n_valid INSIDE the
-    # kernel (the lsh scheme) — an int8 sentinel coordinate can't dominate
-    qp = jnp.pad(q_codes, ((0, pad_q), (0, 0)))
-    cp = jnp.pad(c_codes, ((0, pad_n), (0, 0)))
-    s, i = topk_scores_int8_pallas(qp, cp, k=k_eff, block_q=bq, block_n=bn,
-                                   interpret=tuning.interpret_mode(),
-                                   n_valid=n)
-    if pad_n:
-        bad = i >= n
-        s = jnp.where(bad, -jnp.inf, s)
-        i = jnp.where(bad, -1, i)
-    return _pad_topk(s[:qn], i[:qn], k)
+        oracle = (ref.topk_scores_int8_ref if corpus.dtype == jnp.int8
+                  else ref.topk_scores_ref)
+        s, i = oracle(queries, corpus, k=k_eff, n_valid=n_valid)
+    else:
+        qn = queries.shape[0]
+        bq = min(block_q, max(8, qn))
+        bn = min(block_n, _ceil8(n))
+        qp = jnp.pad(queries.astype(corpus.dtype),
+                     ((0, (-qn) % bq), (0, 0)))
+        s, i = topk_scores_pallas(qp, corpus, n_valid, k=k_eff, block_q=bq,
+                                  block_n=bn,
+                                  interpret=tuning.interpret_mode())
+        s, i = s[:qn], i[:qn]
+    return _pad_topk(s, jnp.where(jnp.isfinite(s), i, -1), k)
 
 
 def gathered_topk(queries: jnp.ndarray, cand_vecs: jnp.ndarray,

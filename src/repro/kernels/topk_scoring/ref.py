@@ -20,20 +20,28 @@ def pad_topk(s: jnp.ndarray, i: jnp.ndarray, k: int):
             jnp.pad(i, ((0, 0), (0, k - k_eff)), constant_values=-1))
 
 
-def topk_scores_ref(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int):
-    scores = jnp.dot(queries, corpus.T, precision=lax.Precision.HIGHEST,
-                     preferred_element_type=jnp.float32)
+def _bounded_topk(scores: jnp.ndarray, k: int, n_valid):
+    """Top-k of (Q, N) scores with columns at or past ``n_valid`` out."""
+    if n_valid is not None:
+        col = jnp.arange(scores.shape[1])[None, :]
+        scores = jnp.where(col < n_valid, scores, -jnp.inf)
     top_s, top_i = lax.top_k(scores, k)
     return top_s, top_i.astype(jnp.int32)
 
 
+def topk_scores_ref(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int,
+                    n_valid=None):
+    scores = jnp.dot(queries, corpus.T, precision=lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    return _bounded_topk(scores, k, n_valid)
+
+
 def topk_scores_int8_ref(q_codes: jnp.ndarray, c_codes: jnp.ndarray, *,
-                         k: int):
+                         k: int, n_valid=None):
     """int8-code oracle: exact int32 dot (|dot| ≤ 127²·D < 2³¹ for any
     realistic D), ranked as f32 like the kernel's partials."""
     scores = jnp.dot(q_codes.astype(jnp.int32), c_codes.astype(jnp.int32).T)
-    top_s, top_i = lax.top_k(scores.astype(jnp.float32), k)
-    return top_s, top_i.astype(jnp.int32)
+    return _bounded_topk(scores.astype(jnp.float32), k, n_valid)
 
 
 def gathered_topk_ref(queries: jnp.ndarray, cand_vecs: jnp.ndarray,

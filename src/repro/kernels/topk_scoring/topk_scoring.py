@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 # f32 scoring runs at full f32 precision on the MXU (the XLA reference
@@ -73,30 +74,29 @@ def merge_partials(partial_s, partial_i, *, k: int, width: int):
     return top_s, jnp.take_along_axis(i, pos, axis=1)
 
 
-def _topk_kernel(q_ref, c_ref, s_out_ref, i_out_ref, *, k: int, width: int,
-                 block_n: int):
+def _topk_kernel(nv_ref, q_ref, c_ref, s_out_ref, i_out_ref, *, k: int,
+                 width: int, block_n: int):
+    """Score one (query block, corpus block) and extract its top-k.  f32
+    rows score at full f32 precision on the MXU; int8 codes dot in int8
+    with an int32 accumulator (the MXU's quantized path), ranking on the
+    raw integer dot — the global query/corpus scales are positive
+    constants, so the int32 order equals the dequantized order.
+
+    Rows at or past the bound ``nv_ref[0]`` (a scalar operand, so it may
+    differ per shard) score −inf: the zero rows a row-sharded corpus pads
+    with, and the ragged last block's rows past the array, which are
+    unspecified on the chip."""
     j = pl.program_id(1)                       # candidate-block index
-    scores = lax.dot_general(q_ref[...], c_ref[...], _NT,
-                             precision=_F32_DOT,
-                             preferred_element_type=jnp.float32)  # (bq, bn)
-    s_out_ref[...], i_out_ref[...] = extract_topk(
-        scores, lambda hit, arg: j * block_n + arg, k=k, width=width)
-
-
-def _topk_int8_kernel(q_ref, c_ref, s_out_ref, i_out_ref, *, k: int,
-                      width: int, block_n: int, n_valid: int):
-    """int8 variant: codes dot in int8 with an int32 accumulator (the MXU's
-    quantized path on TPU), ranking on the raw integer dot — the global
-    query/corpus scales are positive constants, so the int32 order equals
-    the dequantized order.  Padding is masked by true row count
-    (``n_valid``), the lsh kernel's scheme — an int8 sentinel coordinate
-    can't work, the widest code is ±127."""
-    j = pl.program_id(1)
-    scores = lax.dot_general(q_ref[...], c_ref[...], _NT,
-                             preferred_element_type=jnp.int32
-                             ).astype(jnp.float32)
+    if c_ref.dtype == jnp.int8:
+        scores = lax.dot_general(q_ref[...], c_ref[...], _NT,
+                                 preferred_element_type=jnp.int32
+                                 ).astype(jnp.float32)
+    else:
+        scores = lax.dot_general(q_ref[...], c_ref[...], _NT,
+                                 precision=_F32_DOT,
+                                 preferred_element_type=jnp.float32)
     ids = j * block_n + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    scores = jnp.where(ids < n_valid, scores, -jnp.inf)
+    scores = jnp.where(ids < nv_ref[0], scores, -jnp.inf)
     s_out_ref[...], i_out_ref[...] = extract_topk(
         scores, lambda hit, arg: j * block_n + arg, k=k, width=width)
 
@@ -124,12 +124,17 @@ def _gathered_kernel(q_ref, c_ref, i_ref, s_out_ref, i_out_ref, *, k: int,
 
 
 def _partials_call(kernel, grid, in_specs, args, *, qn: int, nc: int,
-                   block_q: int, width: int, interpret: bool):
-    """pallas_call with the shared (Q, nc * width) partial-tile outputs."""
-    out_spec = pl.BlockSpec((block_q, width), lambda i, j: (i, j))
+                   block_q: int, width: int, interpret: bool,
+                   scalars: int = 0):
+    """pallas_call with the shared (Q, nc * width) partial-tile outputs.
+    The first ``scalars`` of ``args`` are scalar operands (scalar
+    prefetch); block index maps take them as trailing arguments."""
+    out_spec = pl.BlockSpec((block_q, width), lambda i, j, *_: (i, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=scalars, grid=grid, in_specs=in_specs,
+        out_specs=[out_spec, out_spec])
     return pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs,
-        out_specs=[out_spec, out_spec],
+        kernel, grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((qn, nc * width), jnp.float32),
                    jax.ShapeDtypeStruct((qn, nc * width), jnp.int32)],
         interpret=interpret,
@@ -164,51 +169,28 @@ def gathered_topk_pallas(queries: jnp.ndarray, cand_vecs: jnp.ndarray,
 
 @functools.partial(jax.jit,
                    static_argnames=("k", "block_q", "block_n", "interpret"))
-def topk_scores_pallas(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int,
-                       block_q: int = 128, block_n: int = 1024,
-                       interpret: bool = False):
-    """queries (Q, D) f32, corpus (N, D) f32 ->
-    (scores (Q, k), ids (Q, k)), inner-product metric.
+def topk_scores_pallas(queries: jnp.ndarray, corpus: jnp.ndarray,
+                       n_valid: jnp.ndarray, *, k: int, block_q: int = 128,
+                       block_n: int = 1024, interpret: bool = False):
+    """queries (Q, D) x corpus (N, D), both f32 or both int8 codes, and a
+    row bound ``n_valid`` (an int32 scalar, traced or not) ->
+    (scores (Q, k), ids (Q, k)), inner-product metric; int8 codes score
+    their integer dot, as f32.  Rows at or past ``n_valid`` score −inf.
 
-    Q must be a multiple of block_q and N of block_n (ops.py pads).
+    Q must be a multiple of block_q (ops.py pads the queries); N need not
+    be one of block_n: the grid runs ``cdiv(N, block_n)`` corpus blocks and
+    the last one's rows past N are masked, so the corpus is never copied.
     """
     qn, d = queries.shape
     n = corpus.shape[0]
-    nq, nc = qn // block_q, n // block_n
+    nq, nc = qn // block_q, pl.cdiv(n, block_n)
     width = tile_width(k)
+    nv = jnp.reshape(jnp.minimum(n_valid, n), (1,)).astype(jnp.int32)
     partial_s, partial_i = _partials_call(
         functools.partial(_topk_kernel, k=k, width=width, block_n=block_n),
         (nq, nc),
-        [pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
-         pl.BlockSpec((block_n, d), lambda i, j: (j, 0))],
-        (queries, corpus), qn=qn, nc=nc, block_q=block_q, width=width,
-        interpret=interpret)
-    return merge_partials(partial_s, partial_i, k=k, width=width)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_q", "block_n", "interpret",
-                                    "n_valid"))
-def topk_scores_int8_pallas(q_codes: jnp.ndarray, c_codes: jnp.ndarray, *,
-                            k: int, block_q: int = 128, block_n: int = 1024,
-                            interpret: bool = False, n_valid: int = None):
-    """q_codes (Q, D) i8, c_codes (N, D) i8 ->
-    (int-dot scores as f32 (Q, k), ids (Q, k)).
-
-    Q must be a multiple of block_q and N of block_n (ops.py pads; rows at
-    or past ``n_valid`` are masked to −inf/−1 inside the kernel).
-    """
-    qn, d = q_codes.shape
-    n = c_codes.shape[0]
-    nq, nc = qn // block_q, n // block_n
-    width = tile_width(k)
-    partial_s, partial_i = _partials_call(
-        functools.partial(_topk_int8_kernel, k=k, width=width,
-                          block_n=block_n,
-                          n_valid=n if n_valid is None else n_valid),
-        (nq, nc),
-        [pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
-         pl.BlockSpec((block_n, d), lambda i, j: (j, 0))],
-        (q_codes, c_codes), qn=qn, nc=nc, block_q=block_q, width=width,
-        interpret=interpret)
+        [pl.BlockSpec((block_q, d), lambda i, j, *_: (i, 0)),
+         pl.BlockSpec((block_n, d), lambda i, j, *_: (j, 0))],
+        (nv, queries, corpus), qn=qn, nc=nc, block_q=block_q, width=width,
+        interpret=interpret, scalars=1)
     return merge_partials(partial_s, partial_i, k=k, width=width)

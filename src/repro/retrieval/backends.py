@@ -88,8 +88,11 @@ class ScoringBackend(Protocol):
         consumes (identity for float backends)."""
         ...
 
-    def topk(self, queries: jnp.ndarray, corpus, *, k: int):
-        """(Q, D) x prepared corpus -> (scores f32[Q, k], ids i32[Q, k])."""
+    def topk(self, queries: jnp.ndarray, corpus, *, k: int, n_valid=None):
+        """(Q, D) x prepared corpus -> (scores f32[Q, k], ids i32[Q, k]).
+        Rows at or past ``n_valid`` (an int or a traced int32 scalar; every
+        row by default) are not candidates: a row-sharded corpus's zero pad
+        rows, which would otherwise outscore genuinely negative rows."""
         ...
 
     def hamming_topk(self, q_codes: jnp.ndarray, c_codes_t: jnp.ndarray, *,
@@ -161,26 +164,28 @@ def rerank_candidates(vecs: jnp.ndarray, queries: jnp.ndarray,
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block"))
-def _blocked_topk(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int,
-                  block: int = 4096):
+def _blocked_topk(queries: jnp.ndarray, corpus: jnp.ndarray, n_valid, *,
+                  k: int, block: int = 4096):
     """Streaming blocked top-k: candidates scored block-by-block with a
     running merge, so the (Q, N) score matrix never materialises — the same
     structure the Pallas topk_scoring kernel implements in VMEM.  Handles
-    k > N natively (the −inf/−1 init survives into the output)."""
+    k > N natively (the −inf/−1 init survives into the output).  The corpus
+    is read in place, never padded: a ragged last block starts early, at
+    N − block, and masks the rows the block before it scored; rows at or
+    past ``n_valid`` are masked likewise."""
     qn, d = queries.shape
     n = corpus.shape[0]
+    block = max(1, min(block, n))
     nb = (n + block - 1) // block
-    pad = nb * block - n
-    cp = jnp.pad(corpus, ((0, pad), (0, 0)))
-    blocks = cp.reshape(nb, block, d)
 
-    def step(carry, xs):
+    def step(carry, bi):
         best_s, best_i = carry
-        blk, bi = xs
+        lo = jnp.minimum(bi * block, n - block)
+        blk = lax.dynamic_slice_in_dim(corpus, lo, block)
         s = jnp.dot(queries, blk.T, precision=lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32)        # (Q, block)
-        ids = bi * block + jnp.arange(block, dtype=jnp.int32)[None]
-        valid = ids < n
+        ids = lo + jnp.arange(block, dtype=jnp.int32)[None]
+        valid = (ids >= bi * block) & (ids < n_valid)
         s = jnp.where(valid, s, -jnp.inf)
         cat_s = jnp.concatenate([best_s, s], axis=1)
         cat_i = jnp.concatenate([best_i, jnp.broadcast_to(ids, s.shape)], 1)
@@ -190,9 +195,9 @@ def _blocked_topk(queries: jnp.ndarray, corpus: jnp.ndarray, *, k: int,
 
     init = (jnp.full((qn, k), -jnp.inf, jnp.float32),
             jnp.full((qn, k), -1, jnp.int32))
-    (scores, ids), _ = lax.scan(
-        step, init, (blocks, jnp.arange(nb, dtype=jnp.int32)))
-    return scores, ids
+    (scores, ids), _ = lax.scan(step, init,
+                                jnp.arange(nb, dtype=jnp.int32))
+    return scores, jnp.where(jnp.isfinite(scores), ids, -1)
 
 
 @register_backend
@@ -207,9 +212,11 @@ class JnpBackend:
     def prepare_corpus(self, vecs):
         return vecs
 
-    def topk(self, queries, corpus, *, k: int):
-        return _blocked_topk(queries, _float_corpus(corpus), k=k,
-                             block=self.block)
+    def topk(self, queries, corpus, *, k: int, n_valid=None):
+        corpus = _float_corpus(corpus)
+        return _blocked_topk(queries, corpus,
+                             corpus.shape[0] if n_valid is None else n_valid,
+                             k=k, block=self.block)
 
     def hamming_topk(self, q_codes, c_codes_t, *, k: int):
         k_eff = min(k, c_codes_t.shape[1])
@@ -239,9 +246,9 @@ class PallasBackend:
     def prepare_corpus(self, vecs):
         return vecs
 
-    def topk(self, queries, corpus, *, k: int):
+    def topk(self, queries, corpus, *, k: int, n_valid=None):
         return topk_ops.topk_scores(queries, _float_corpus(corpus), k=k,
-                                    block_q=self.block_q,
+                                    n_valid=n_valid, block_q=self.block_q,
                                     block_n=self.block_n)
 
     def hamming_topk(self, q_codes, c_codes_t, *, k: int):
@@ -281,13 +288,14 @@ class Int8Backend:
         codes, scale = quantize_int8(vecs)
         return QuantizedCorpus(codes, scale, vecs)
 
-    def topk(self, queries, corpus, *, k: int):
+    def topk(self, queries, corpus, *, k: int, n_valid=None):
         qc = (corpus if isinstance(corpus, QuantizedCorpus)
               else self.prepare_corpus(corpus))
         n = qc.codes.shape[0]
         pool = min(max(self.rerank_factor * k, k), n)
         q_codes, _ = quantize_int8(jnp.asarray(queries, jnp.float32))
         _, cand = topk_ops.topk_scores_int8(q_codes, qc.codes, k=pool,
+                                            n_valid=n_valid,
                                             block_q=self.block_q,
                                             block_n=self.block_n)
         return rerank_candidates(qc.vecs, queries, cand, k=k)

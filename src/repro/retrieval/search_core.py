@@ -36,7 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed.sharded_corpus import ShardedCorpus
+from repro.distributed.sharded_corpus import (ShardedCorpus,
+                                              resolve_corpus_axes)
 from repro.obs import trace
 from repro.obs import memory as obs_memory
 from repro.retrieval.backends import get_backend
@@ -105,9 +106,10 @@ class SearchSession:
             # lifted on the born path (per-shard scales + float rerank);
             # the global-partition path keeps the rejection (DESIGN.md §13)
             raise ValueError(
-                "sharded search does not support the 'int8' backend (the "
-                "row-shard padding sentinel would destroy the quantization "
-                "scale); use backend='jnp' or 'pallas'")
+                "sharded search does not support the 'int8' backend on the "
+                "global-partition plans (one quantization scale over rows "
+                "split across shards); use backend='jnp' or 'pallas', or a "
+                "sharded-from-birth corpus (streamed=True)")
         if cfg.engine_opts:
             engine = dataclasses.replace(engine, **dict(cfg.engine_opts))
         self.config = cfg
@@ -115,9 +117,14 @@ class SearchSession:
         self._born = born
         if born is not None:
             self.corpus_size = born.n
+            self._shards = born.num_shards
         else:
             vecs = jnp.asarray(corpus_vecs)
             self.corpus_size = int(vecs.shape[0])
+            self._shards = (int(np.prod([cfg.mesh.shape[a] for a in
+                                         resolve_corpus_axes(cfg.mesh, None)]))
+                            if cfg.sharded else 1)
+        self._rows_per_shard = -(-self.corpus_size // self._shards)
         self.ids_map = None if ids_map is None else np.asarray(ids_map)
         if self.ids_map is not None and self.ids_map.size != self.corpus_size:
             raise ValueError(
@@ -145,7 +152,8 @@ class SearchSession:
                              f"{self.corpus_size}/{queries.shape[0]}/{k}"),
                 engine=cfg.engine, backend=cfg.backend,
                 n=self.corpus_size, q=int(queries.shape[0]), k=k,
-                sharded=cfg.sharded) as sp:
+                sharded=cfg.sharded, shards=self._shards,
+                rows_per_shard=self._rows_per_shard) as sp:
             if cfg.sharded:
                 scores, ids = sharded_search(self.engine, self.index,
                                              queries, k=k, mesh=cfg.mesh)

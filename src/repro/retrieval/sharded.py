@@ -48,14 +48,22 @@ Partition plans per engine (both generations share the merge):
 
 Padding invariants: rows/lists pad to a multiple of the shard count; padded
 rows mask to −inf/−1 before the merge and can never displace a real
-candidate.  Born pads are born masked: LSH pad rows carry W+1 all-ones
-extra code words, IVF pad rows assign to a dummy list that is never
-probed, int8 widens the local candidate pool by the global pad count so a
-zero-code pad row can never push a real candidate out of the pool.
+candidate.  Dense rows (exact, tfidf, int8 codes) pad with zeros and each
+shard gives the top-k kernel its row bound, ``n − row0`` clipped to the
+shard, which masks the rest inside the kernel: the rows are scanned where
+they lie and never copied.  LSH pad rows carry W+1 all-ones extra code
+words, IVF pad rows assign to a dummy list that is never probed.
+
+Every born plan runs as one compiled program per (index type, shapes, k,
+mesh): :func:`sharded_search` dispatches to a jitted ``_born_search``,
+whose indexes are pytrees with their row count static, so a call after a
+shape's first compiles nothing.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+import dataclasses
+import functools
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,11 +77,16 @@ from repro.distributed.sharded_corpus import ShardedCorpus
 from repro.distributed.sharding import RETRIEVAL_RULES, partition_axes
 from repro.kernels.topk_scoring import ops as topk_ops
 from repro.kernels.topk_scoring.ref import pad_topk as _pad_topk
+from repro.obs.metrics import REGISTRY
 from repro.retrieval.backends import get_backend, rerank_candidates
 from repro.retrieval.ivfflat import assign_lists, fill_lists, list_ranks
 from repro.retrieval.lsh import encode
 
 _F32 = lax.Precision.HIGHEST      # XLA's TPU default rounds f32 to bf16
+
+#: counter of the partial-list entries the merge gathers: queries x k x
+#: shards a call
+MERGE_CANDIDATES = "search.merge.candidates"
 
 
 def _resolve_axes(mesh: Mesh, axes: Optional[tuple]) -> tuple:
@@ -106,11 +119,28 @@ def _col_spec(axes: tuple) -> P:
 
 def _merge(s: jnp.ndarray, i: jnp.ndarray, axes: tuple, k: int):
     """All-gather per-shard (scores, ids) partials along the k axis and
-    reduce to the global top-k (replicated on every shard)."""
-    s = lax.all_gather(s, axes, axis=1, tiled=True)
-    i = lax.all_gather(i, axes, axis=1, tiled=True)
-    top_s, pos = lax.top_k(s, min(k, s.shape[1]))
-    return top_s, jnp.take_along_axis(i, pos, axis=1)
+    reduce to the global top-k (replicated on every shard).  Its device
+    ops sit under the ``search.merge`` scope in a profiler trace."""
+    with jax.named_scope("search.merge"):
+        s = lax.all_gather(s, axes, axis=1, tiled=True)
+        i = lax.all_gather(i, axes, axis=1, tiled=True)
+        top_s, pos = lax.top_k(s, min(k, s.shape[1]))
+        return top_s, jnp.take_along_axis(i, pos, axis=1)
+
+
+def _shard_bound(row0, rows: int, n: int):
+    """Rows of a shard that starting at global row ``row0`` hold corpus
+    rows (the rest are zero pad): the top-k kernel's row bound."""
+    return jnp.clip(n - row0, 0, rows)
+
+
+def _dense_shard_topk(backend, v_l, q, *, rows: int, n: int, k_l: int,
+                      k: int, axes: tuple):
+    """One shard's dense top-k over its rows, bounded to the corpus rows it
+    holds, merged across the shards with global ids."""
+    row0 = coll.flat_axis_index(axes) * rows
+    s, i = backend.topk(q, v_l, k=k_l, n_valid=_shard_bound(row0, rows, n))
+    return _merge(s, jnp.where(i >= 0, row0 + i, -1), axes, k)
 
 
 def _rowwise_topk(backend, vecs: jnp.ndarray, queries: jnp.ndarray, *,
@@ -120,31 +150,14 @@ def _rowwise_topk(backend, vecs: jnp.ndarray, queries: jnp.ndarray, *,
     .. deprecated:: part of the build-globally-then-partition path — the
        full index is resident on every device before the scan.  Prefer a
        sharded-from-birth build (:func:`sharded_build`)."""
-    n, dim = vecs.shape
+    n = vecs.shape[0]
     d = _axis_count(mesh, axes)
     rows = -(-n // d)
-    k_l = min(k, rows)
     pad = rows * d - n
-    if pad:
-        # sentinel coordinate (the kernels/topk_scoring/ops.py trick):
-        # queries get 1.0, real rows 0.0, padded rows -BIG, so a padded row
-        # scores -BIG and can never displace a real candidate from the
-        # LOCAL top-k (a zero-padded row would score 0 and beat genuinely
-        # negative candidates before the post-hoc validity mask)
-        queries = jnp.pad(queries, ((0, 0), (0, 1)), constant_values=1.0)
-        vp = jnp.pad(vecs, ((0, pad), (0, 1)))
-        vp = vp.at[n:, dim].set(-1e30)
-    else:
-        vp = vecs
-
-    def shard_fn(v_l, q):
-        row0 = coll.flat_axis_index(axes) * rows
-        s, i = backend.topk(q, v_l, k=k_l)
-        gid = row0 + i
-        ok = (i >= 0) & (gid < n)
-        return _merge(jnp.where(ok, s, -jnp.inf),
-                      jnp.where(ok, gid, -1), axes, k)
-
+    # zero pad rows: each shard's row bound keeps them out of its top-k
+    vp = jnp.pad(vecs, ((0, pad), (0, 0))) if pad else vecs
+    shard_fn = functools.partial(_dense_shard_topk, backend, rows=rows, n=n,
+                                 k_l=min(k, rows), k=k, axes=axes)
     fn = shard_map(shard_fn, mesh=mesh,
                    in_specs=(_row_spec(axes, 2), P(None, None)),
                    out_specs=(P(), P()), check_vma=False)
@@ -257,28 +270,40 @@ _SHARDED_IMPLS: Dict[str, Callable] = {
 # ---------------------------------------------------------------------------
 
 
-class ShardedFlatIndex(NamedTuple):
-    """Born-sharded dense rows (exact engine).  ``aug`` marks the padding
-    sentinel column (present only when the corpus needed tail padding, so a
-    1-device build stays bit-identical to the global build)."""
-
-    vecs: Any        # f32[rows·d, D(+1)] row-sharded
-    n: int
-    aug: bool
+def _born_index(cls):
+    """A born index: a frozen dataclass registered as a pytree whose arrays
+    are leaves and whose row count ``n`` (and LSH's ``aug``) are static, so
+    a jitted plan compiles once per index type, shapes and row count."""
+    return jax.tree_util.register_dataclass(dataclasses.dataclass(
+        frozen=True)(cls))
 
 
-class ShardedTfIdfIndex(NamedTuple):
+def _static():
+    return dataclasses.field(metadata=dict(static=True))
+
+
+@_born_index
+class ShardedFlatIndex:
+    """Born-sharded dense rows (exact engine): the ``ShardedCorpus``'s own
+    buffer, zero past ``n``."""
+
+    vecs: Any        # f32[rows·d, D] row-sharded
+    n: int = _static()
+
+
+@_born_index
+class ShardedTfIdfIndex:
     """Born-sharded IDF-weighted rows; ``weights`` replicate (they are an
     O(D) statistic reduced with an integer psum — bit-identical on any
     mesh)."""
 
-    vecs: Any        # f32[rows·d, D(+1)] row-sharded, IDF-weighted
+    vecs: Any        # f32[rows·d, D] row-sharded, IDF-weighted
     weights: Any     # f32[D] replicated
-    n: int
-    aug: bool
+    n: int = _static()
 
 
-class ShardedQuantIndex(NamedTuple):
+@_born_index
+class ShardedQuantIndex:
     """Born-sharded int8 corpus: per-shard codes with shard-local scales
     (PR 5's rejection lifted — ranking within a shard is scale-invariant,
     and cross-shard merging happens after the float rerank, so no global
@@ -288,21 +313,23 @@ class ShardedQuantIndex(NamedTuple):
     codes: Any       # i8[rows·d, D] row-sharded
     scales: Any      # f32[d] one max-abs scale per shard
     vecs: Any        # f32[rows·d, D] row-sharded
-    n: int
+    n: int = _static()
 
 
-class ShardedLSHIndex(NamedTuple):
+@_born_index
+class ShardedLSHIndex:
     """Born-sharded LSH: codes encoded shard-locally from the replicated
     projection; ``aug`` marks the W+1 all-ones pad-sentinel words."""
 
     proj: Any        # f32[D, n_bits] replicated
     codes: Any       # i32[W(+W+1), rows·d] transposed, column-sharded
     vecs: Any        # f32[rows·d, D] row-sharded (rerank)
-    n: int
-    aug: bool
+    n: int = _static()
+    aug: bool = _static()
 
 
-class ShardedIVFIndex(NamedTuple):
+@_born_index
+class ShardedIVFIndex:
     """Born-sharded IVF: lists partitioned by row ORIGIN shard — each shard
     holds a (n_lists, cap_local) ELL of its own rows per global list, so
     no row ever moves between shards at build time.  Centroids replicate
@@ -315,11 +342,7 @@ class ShardedIVFIndex(NamedTuple):
     vecs: Any        # f32[d·n_lists, cap_local, D] row-sharded by origin
     ids: Any         # i32[d·n_lists, cap_local] global ids, −1 pad
     mask: Any        # bool[d·n_lists, cap_local]
-    n: int
-
-
-_BORN_INDEX_TYPES = (ShardedFlatIndex, ShardedTfIdfIndex, ShardedQuantIndex,
-                     ShardedLSHIndex, ShardedIVFIndex)
+    n: int = _static()
 
 
 def _shard_geometry(corpus: ShardedCorpus):
@@ -331,26 +354,6 @@ def _shard_geometry(corpus: ShardedCorpus):
 
 def _local_valid(row0, rows: int, n: int):
     return (row0 + jnp.arange(rows, dtype=jnp.int32)) < n
-
-
-def _augment_rows(corpus: ShardedCorpus, row_vecs):
-    """Append the −1e30/0.0 pad-sentinel column shard-locally (only when
-    the corpus has pad rows — a pad-free build adds nothing, preserving
-    1-device bit parity with the global build)."""
-    axes, d, rows, pad = _shard_geometry(corpus)
-    if not pad:
-        return row_vecs, False
-    n = corpus.n
-
-    def f(v_l):
-        row0 = coll.flat_axis_index(axes) * rows
-        sent = jnp.where(_local_valid(row0, rows, n), 0.0,
-                         -1e30).astype(v_l.dtype)
-        return jnp.concatenate([v_l, sent[:, None]], axis=1)
-
-    fn = shard_map(f, mesh=corpus.mesh, in_specs=(_row_spec(axes, 2),),
-                   out_specs=_row_spec(axes, 2), check_vma=False)
-    return fn(row_vecs), True
 
 
 def _quant_build(corpus: ShardedCorpus, row_vecs) -> ShardedQuantIndex:
@@ -377,8 +380,7 @@ def _build_born_exact(engine, corpus: ShardedCorpus, key):
     del key  # deterministic
     if engine.backend == "int8":
         return _quant_build(corpus, corpus.vecs)
-    vecs, aug = _augment_rows(corpus, corpus.vecs)
-    return ShardedFlatIndex(vecs, corpus.n, aug)
+    return ShardedFlatIndex(corpus.vecs, corpus.n)
 
 
 def _build_born_tfidf(engine, corpus: ShardedCorpus, key):
@@ -400,9 +402,8 @@ def _build_born_tfidf(engine, corpus: ShardedCorpus, key):
     weighted, w = fn(corpus.vecs)
     if engine.backend == "int8":
         quant = _quant_build(corpus, weighted)
-        return ShardedTfIdfIndex(quant, w, corpus.n, False)
-    weighted, aug = _augment_rows(corpus, weighted)
-    return ShardedTfIdfIndex(weighted, w, corpus.n, aug)
+        return ShardedTfIdfIndex(quant, w, corpus.n)
+    return ShardedTfIdfIndex(weighted, w, corpus.n)
 
 
 def _build_born_lsh(engine, corpus: ShardedCorpus, key):
@@ -548,53 +549,42 @@ def _distributed_rerank(v_l, q, cand, row0, rows: int, k: int, axes):
     return _pad_topk(top_s, top_i, k)
 
 
-def _search_born_rows(backend, index_vecs, n: int, aug: bool, queries, *,
-                      k: int, mesh, axes):
-    """Shard-local dense scan over born rows (exact / tfidf): the sentinel
-    column was appended at build time, so this is ``_rowwise_topk`` minus
-    the global pad step."""
+def _search_born_rows(engine, index, queries, *, k: int, mesh, axes):
+    """Shard-local dense scan over born rows (exact / tfidf): each shard
+    scans the rows it holds, bounded to the corpus rows among them, so
+    this is ``_rowwise_topk`` minus the global pad step."""
     d = _axis_count(mesh, axes)
-    rows = index_vecs.shape[0] // d
-    k_l = min(k, rows)
-    if aug:
-        queries = jnp.pad(queries, ((0, 0), (0, 1)), constant_values=1.0)
-
-    def f(v_l, q):
-        row0 = coll.flat_axis_index(axes) * rows
-        s, i = backend.topk(q, v_l, k=k_l)
-        gid = row0 + i
-        ok = (i >= 0) & (gid < n)
-        return _merge(jnp.where(ok, s, -jnp.inf),
-                      jnp.where(ok, gid, -1), axes, k)
-
+    rows = index.vecs.shape[0] // d
+    f = functools.partial(_dense_shard_topk, get_backend(engine.backend),
+                          rows=rows, n=index.n, k_l=min(k, rows), k=k,
+                          axes=axes)
     fn = shard_map(f, mesh=mesh,
                    in_specs=(_row_spec(axes, 2), P(None, None)),
                    out_specs=(P(), P()), check_vma=False)
-    return _pad_topk(*fn(index_vecs, queries), k)
+    return _pad_topk(*fn(index.vecs, queries), k)
 
 
-def _search_born_quant(backend, index: ShardedQuantIndex, queries, *,
+def _search_born_quant(engine, index: ShardedQuantIndex, queries, *,
                        k: int, mesh, axes):
     """Born int8 plan: per-shard quantized scan (shard-local codes — the
-    integer ranking is invariant to the shard's own scale), candidate ids
-    all-gathered, float rerank distributed over the sharded rows.  The
-    local pool widens by the global pad count so zero-code pad rows can
-    never displace a real candidate (they score 0, which beats genuinely
-    negative rows before the validity mask)."""
+    integer ranking is invariant to the shard's own scale) bounded to the
+    shard's corpus rows, so zero-code pad rows never enter the pool;
+    candidate ids all-gathered, float rerank distributed over the sharded
+    rows."""
+    backend = get_backend(engine.backend)
     d = _axis_count(mesh, axes)
     rows = index.codes.shape[0] // d
     n = index.n
-    pad = rows * d - n
-    pool = min(max(backend.rerank_factor * k, k), n)
-    pool_l = min(pool + pad, rows)
+    pool_l = min(max(backend.rerank_factor * k, k), n, rows)
     q_codes, _ = quantize_int8(jnp.asarray(queries, jnp.float32))
 
     def f(c_l, v_l, qc, q):
         row0 = coll.flat_axis_index(axes) * rows
         _, i = topk_ops.topk_scores_int8(qc, c_l, k=pool_l,
+                                         n_valid=_shard_bound(row0, rows, n),
                                          block_q=backend.block_q,
                                          block_n=backend.block_n)
-        gid = jnp.where((i >= 0) & (row0 + i < n), row0 + i, -1)
+        gid = jnp.where(i >= 0, row0 + i, -1)
         cand = lax.all_gather(gid, axes, axis=1, tiled=True)
         return _distributed_rerank(v_l, q, cand, row0, rows, k, axes)
 
@@ -670,6 +660,15 @@ def _search_born_ivf(engine, index: ShardedIVFIndex, queries, *, k: int,
                          index.centroids, queries), k)
 
 
+_BORN_PLANS: Dict[type, Callable] = {
+    ShardedFlatIndex: _search_born_rows,
+    ShardedTfIdfIndex: _search_born_rows,
+    ShardedQuantIndex: _search_born_quant,
+    ShardedLSHIndex: _search_born_lsh,
+    ShardedIVFIndex: _search_born_ivf,
+}
+
+
 def sharded_buffer_topk(buf_vecs, n_valid, queries, *, k: int, mesh: Mesh,
                         axes: Optional[tuple] = None, id_base: int = 0):
     """Dense exact top-k over a fixed-capacity row-sharded append buffer
@@ -704,29 +703,21 @@ def sharded_buffer_topk(buf_vecs, n_valid, queries, *, k: int, mesh: Mesh,
     return _pad_topk(*fn(buf_vecs, queries, jnp.int32(n_valid)), k)
 
 
-def _born_search(engine, index, queries, *, k: int, mesh, axes):
-    if isinstance(index, ShardedFlatIndex):
-        return _search_born_rows(get_backend(engine.backend), index.vecs,
-                                 index.n, index.aug, queries, k=k,
-                                 mesh=mesh, axes=axes)
-    if isinstance(index, ShardedTfIdfIndex):
-        if isinstance(index.vecs, ShardedQuantIndex):
-            return _search_born_quant(get_backend(engine.backend),
-                                      index.vecs, queries, k=k, mesh=mesh,
-                                      axes=axes)
-        return _search_born_rows(get_backend(engine.backend), index.vecs,
-                                 index.n, index.aug, queries, k=k,
-                                 mesh=mesh, axes=axes)
-    if isinstance(index, ShardedQuantIndex):
-        return _search_born_quant(get_backend(engine.backend), index,
-                                  queries, k=k, mesh=mesh, axes=axes)
-    if isinstance(index, ShardedLSHIndex):
-        return _search_born_lsh(engine, index, queries, k=k, mesh=mesh,
-                                axes=axes)
-    if isinstance(index, ShardedIVFIndex):
-        return _search_born_ivf(engine, index, queries, k=k, mesh=mesh,
-                                axes=axes)
-    raise TypeError(f"not a born-sharded index: {type(index).__name__}")
+def _born_plan(index):
+    """(plan, the index it runs on) for a born index: dispatch by type, in
+    Python, before the plan is traced."""
+    if isinstance(index, ShardedTfIdfIndex) and \
+            isinstance(index.vecs, ShardedQuantIndex):
+        return _search_born_quant, index.vecs
+    return _BORN_PLANS[type(index)], index
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("plan", "engine", "k", "mesh", "axes"))
+def _born_search(plan, engine, index, queries, *, k: int, mesh, axes):
+    """One born plan as one compiled program per (plan, engine, index
+    shapes and row count, k, mesh)."""
+    return plan(engine, index, queries, k=k, mesh=mesh, axes=axes)
 
 
 def sharded_search(engine, index, queries: jnp.ndarray, *, k: int,
@@ -741,22 +732,25 @@ def sharded_search(engine, index, queries: jnp.ndarray, *, k: int,
     Born indexes from :func:`sharded_build` route to the shard-local
     plans (including int8); a pre-built global index falls through to the
     deprecated build-globally-then-partition plans below."""
-    if isinstance(index, _BORN_INDEX_TYPES):
-        return _born_search(engine, index, queries, k=k, mesh=mesh,
-                            axes=_resolve_axes(mesh, axes))
-    if getattr(engine, "backend", None) == "int8":
-        # the row-shard padding sentinel (−1e30 coordinate) would destroy
-        # the int8 corpus scale on THIS (deprecated, global-partition)
-        # path; the born path supports int8 via per-shard scales + float
-        # rerank — build with ``sharded_build`` instead (DESIGN.md §13)
+    axes = _resolve_axes(mesh, axes)
+    born = type(index) in _BORN_PLANS
+    if not born and getattr(engine, "backend", None) == "int8":
+        # the deprecated global-partition plans have no int8 plan (one
+        # global quantization scale over rows split across shards); the
+        # born path supports int8 via per-shard scales + float rerank —
+        # build with ``sharded_build`` instead (DESIGN.md §13)
         raise ValueError(
             "sharded search does not support the 'int8' backend; use "
             "backend='jnp' or 'pallas' for sharded meshes")
-    try:
-        impl = _SHARDED_IMPLS[engine.name]
-    except KeyError:
+    if not born and engine.name not in _SHARDED_IMPLS:
         raise ValueError(
             f"no sharded search plan for engine {engine.name!r}; engines "
-            f"with plans: {', '.join(sorted(_SHARDED_IMPLS))}") from None
-    return impl(engine, index, queries, k=k, mesh=mesh,
-                axes=_resolve_axes(mesh, axes))
+            f"with plans: {', '.join(sorted(_SHARDED_IMPLS))}")
+    REGISTRY.counter(MERGE_CANDIDATES).inc(
+        int(queries.shape[0]) * k * _axis_count(mesh, axes))
+    if born:
+        plan, index = _born_plan(index)
+        return _born_search(plan, engine, index, queries, k=k, mesh=mesh,
+                            axes=axes)
+    return _SHARDED_IMPLS[engine.name](engine, index, queries, k=k,
+                                       mesh=mesh, axes=axes)

@@ -60,6 +60,46 @@ def test_topk_scoring_odd_shapes(q, n, d, k, use_kernel):
     assert np.isneginf(np.asarray(s)[:, k_eff:]).all()
 
 
+@pytest.mark.parametrize("n,n_valid", [(1000, None), (3001, None),
+                                         (3001, 2500), (3001, 7)])
+def test_topk_scoring_ragged_and_row_bound(n, n_valid):
+    """No corpus copy: N need not be a multiple of block_n (the kernel masks
+    its ragged last block) and rows at or past ``n_valid`` are not
+    candidates — the result is the oracle's over the first n_valid rows."""
+    q, d, k = 19, 32, 10
+    qs = jax.random.normal(jax.random.PRNGKey(n), (q, d))
+    cs = jax.random.normal(jax.random.PRNGKey(1), (n, d))
+    s, i = topk_scores(qs, cs, k=k, n_valid=n_valid, block_q=8,
+                       block_n=1024)
+    m = n if n_valid is None else n_valid
+    k_eff = min(k, m)
+    s_ref, i_ref = topk_scores_ref(qs, cs[:m], k=k_eff)
+    np.testing.assert_allclose(np.asarray(s)[:, :k_eff], np.asarray(s_ref),
+                               rtol=1e-5, atol=1e-5)
+    assert (np.asarray(i)[:, :k_eff] == np.asarray(i_ref)).all()
+    assert (np.asarray(i)[:, k_eff:] == -1).all()
+    assert np.isneginf(np.asarray(s)[:, k_eff:]).all()
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+def test_topk_scoring_row_bound_all_negative(use_kernel):
+    """Every real score negative and the rows past ``n_valid`` zero, as a
+    row-sharded corpus pads its last shard: the zero rows would score 0 and
+    win, so the bound alone keeps them out (a traced bound, as a shard
+    computes it from its axis index)."""
+    qs = jnp.abs(jax.random.normal(jax.random.PRNGKey(0), (5, 16))) + 0.1
+    real = -jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (2900, 16)))
+    cs = jnp.concatenate([real, jnp.zeros((101, 16))])     # 3,001 rows
+    s, i = jax.jit(lambda q, c, nv: topk_scores(
+        q, c, k=10, n_valid=nv, block_n=1024, use_kernel=use_kernel))(
+        qs, cs, jnp.int32(2900))
+    s_ref, i_ref = topk_scores_ref(qs, real, k=10)
+    assert (np.asarray(s) < 0).all()
+    assert (np.asarray(i) == np.asarray(i_ref)).all()
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), rtol=1e-5,
+                               atol=1e-5)
+
+
 @pytest.mark.parametrize("q,n,d,k", [
     (16, 256, 32, 3), (64, 1000, 64, 8), (7, 513, 16, 5),
     (3, 50, 16, 7),           # q and n below the block floors

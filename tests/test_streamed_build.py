@@ -182,11 +182,11 @@ def test_streamed_sampler_accepts_prebuilt_qrels(mesh):
 # ---------------------------------------------------------------------------
 
 def test_legacy_sharded_int8_rejection_pinned(mesh, data):
-    """The build-globally-then-partition path keeps rejecting int8 (the
-    padding sentinel would destroy the shard's quantization scale) — the
-    born path is the supported route.  Both messages are pinned."""
+    """The build-globally-then-partition path keeps rejecting int8 (it
+    has no plan for one quantization scale over rows split across shards)
+    — the born path is the supported route.  Both messages are pinned."""
     vecs, queries = data
-    with pytest.raises(ValueError, match="padding sentinel would destroy"):
+    with pytest.raises(ValueError, match="one quantization scale over rows"):
         SearchSession(vecs, SearchConfig(sharded=True, backend="int8",
                                          mesh=mesh))
     eng = dataclasses.replace(get_retrieval_engine("exact"), backend="int8")
@@ -313,3 +313,60 @@ def test_streamed_two_device_mesh():
                          timeout=900)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "STREAM-2DEV-OK" in out.stdout
+
+
+# ---------------------------------------------------------------------------
+# 4-device host mesh: born exact search reads the corpus in place, compiled
+# ---------------------------------------------------------------------------
+
+_FOUR_DEVICE_SCRIPT = r"""
+import numpy as np
+import jax
+from jax.sharding import Mesh
+
+from repro.distributed.sharded_corpus import ShardedCorpus
+from repro.obs import recompile
+from repro.retrieval.search_core import SearchConfig, SearchSession
+
+assert len(jax.devices()) == 4, jax.devices()
+mesh = Mesh(np.array(jax.devices()), ("data",))
+rng = np.random.default_rng(0)
+# 2,501 rows a device: the last device holds 3 zero pad rows, and each
+# shard's kernel grid ends in a ragged block at block_n 1024
+vecs = rng.standard_normal((10_001, 32)).astype(np.float32)
+queries = rng.standard_normal((40, 32)).astype(np.float32)
+recompile.enable()
+for backend in ("jnp", "pallas"):
+    ref_s, ref_i = SearchSession(vecs, SearchConfig(
+        backend=backend)).search_scored(queries, k=10)
+    corpus = ShardedCorpus.from_host(vecs, mesh=mesh)
+    session = SearchSession(corpus, SearchConfig(backend=backend,
+                                                 query_chunk=16))
+    assert session.index.vecs is corpus.vecs, backend   # no copy at build
+    for call in range(3):
+        recompile.mark()
+        s, i = session.search_scored(queries, k=10)
+        if call:
+            assert recompile.since() == 0, (backend, call,
+                                            recompile.counts())
+        assert np.array_equal(np.sort(i, 1), np.sort(ref_i, 1)), backend
+        np.testing.assert_allclose(s, ref_s, rtol=1e-5, atol=1e-5)
+print("FOUR-DEVICE-OK")
+"""
+
+
+def test_born_exact_four_device_mesh_in_place_and_compiled():
+    """Born exact search over 10,001 rows on a 4-device mesh equals
+    single-device exact search (jnp and pallas backends); the index's rows
+    are the corpus's own buffer, and after the first pass (one compile per
+    chunk shape) a pass compiles nothing.  Subprocess because the test
+    session itself must see 1 CPU device."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=src + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    out = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_SCRIPT],
+                         env=env, capture_output=True, text=True,
+                         timeout=900)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "FOUR-DEVICE-OK" in out.stdout

@@ -92,6 +92,25 @@ def test_topk_scores_compiles(on_v5e, one_chip, k):
     assert "tpu_custom_call" in text
 
 
+#: one chip's share of MS MARCO passages over one four-chip host:
+#: ceil(8,841,823 / 4) rows
+SHARD_ROWS = 2_210_456
+
+
+@pytest.mark.parametrize("n", [SHARD_ROWS, 1001])
+def test_topk_scores_reads_the_corpus_in_place(on_v5e, one_chip, n):
+    """A chip's full share of the corpus (a ragged last block of 664 rows)
+    and a corpus under one block: the program's temporaries stay under
+    1 GB, so the corpus is never copied to pad it (the partial top-k tiles
+    of 256 queries over 2,159 blocks are about 0.57 GB)."""
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in ((Q, D), (n, D))]
+    compiled = jax.jit(lambda q, c: topk_scores(q, c, k=10)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.parametrize("k", [10, 40])
 def test_topk_scores_int8_compiles(on_v5e, one_chip, k):
     text = _compile(lambda q, c: topk_scores_int8(q, c, k=k), one_chip,
